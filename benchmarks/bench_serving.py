@@ -86,6 +86,7 @@ from repro.core.sparse_linear import SparsityConfig, pack_params
 from repro.models.config import ModelConfig
 from repro.serving import (Engine, ServeConfig, build_decode_step,
                            build_prefill_step, sample_token)
+from repro.launch.mesh import make_mesh
 
 SMOKE = os.environ.get("REPRO_BENCH_SMOKE", "") == "1"
 
@@ -240,7 +241,7 @@ def _serve_per_token(cfg, mesh, params, slots, requests):
     def run_waves(reqs):
         toks = 0
         queue = list(reqs)
-        with mesh:
+        with jax.set_mesh(mesh):
             while queue:
                 active, queue = queue[:slots], queue[slots:]
                 prompts = np.zeros((slots, PROMPT_PAD), np.int32)
@@ -640,7 +641,7 @@ def _sharded_scenario(rng) -> list:
     for d in SD_DEVICES:
         if d > jax.device_count():
             continue
-        mesh = jax.make_mesh((1, d), ("data", "model"))
+        mesh = make_mesh((1, d))
         scfg = ServeConfig(slots=SD_SLOTS, max_len=MAX_LEN,
                            prompt_pad=PROMPT_PAD,
                            max_new_tokens=SD_MAX_NEW,
@@ -665,7 +666,7 @@ def _sharded_scenario(rng) -> list:
 
 def run() -> dict:
     rng = np.random.default_rng(0)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh()
     rows = []
     for fmt in SPARSITY:
         cfg, params = _model(fmt)

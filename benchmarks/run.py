@@ -62,6 +62,8 @@ def main(argv=None) -> int:
                          "swept on TPU only)")
     args = ap.parse_args(argv)
 
+    from repro.core.caches import configure_caches
+    configure_caches()
     if args.warm_autotune:
         from benchmarks import warm_autotune
         out = warm_autotune.run(slots=args.slots,

@@ -17,12 +17,13 @@ import numpy as np
 from repro import configs as C
 from repro import models as MZ
 from repro.serving import Engine, ServeConfig
+from repro.launch.mesh import make_mesh
 
 
 def main():
     cfg = C.get_reduced("qwen3-0.6b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with mesh:
+    mesh = make_mesh()
+    with jax.set_mesh(mesh):
         params = MZ.init_model(jax.random.key(0), cfg)
 
     scfg = ServeConfig(slots=4, max_len=256, prompt_pad=32,

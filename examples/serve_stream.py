@@ -22,6 +22,7 @@ import numpy as np
 from repro import models as MZ
 from repro.models.config import ModelConfig
 from repro.serving import Engine, RequestStatus, ServeConfig
+from repro.launch.mesh import make_mesh
 
 CFG = ModelConfig(name="stream-demo", n_layers=2, d_model=64,
                   vocab_size=512, n_heads=4, n_kv_heads=2, d_ff=128,
@@ -29,8 +30,8 @@ CFG = ModelConfig(name="stream-demo", n_layers=2, d_model=64,
 
 
 def main():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with mesh:
+    mesh = make_mesh()
+    with jax.set_mesh(mesh):
         params = MZ.init_model(jax.random.key(0), CFG)
 
     scfg = ServeConfig(slots=2, max_len=128, prompt_pad=16,

@@ -31,6 +31,7 @@ from repro.data import DataConfig, make_batch
 from repro.models.config import ModelConfig
 from repro.train import TrainConfig, Trainer
 from repro.train.trainer import build_train_step, init_opt_state
+from repro.launch.mesh import make_mesh
 
 
 def lm_config(small: bool) -> ModelConfig:
@@ -69,7 +70,7 @@ def main():
     args = ap.parse_args()
 
     cfg = lm_config(args.small)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh()
     dcfg = DataConfig(seed=0, global_batch=args.batch, seq_len=args.seq)
     print(f"model: {cfg.name}  params≈{cfg.param_count()/1e6:.1f}M")
 
@@ -98,7 +99,7 @@ def main():
         cfg, ft_cfg, mesh, jax.eval_shape(lambda: params),
         batch0, masks=masks)
     opt = init_opt_state(params, ft_cfg)
-    with mesh:
+    with jax.set_mesh(mesh):
         for s in range(args.finetune_steps):
             batch = make_batch(cfg, dcfg, 10_000 + s)
             params, opt, metrics = step_fn(params, opt, batch)

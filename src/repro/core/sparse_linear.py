@@ -243,7 +243,7 @@ def abstract_pack(K: int, N: int, cfg: SparsityConfig, dtype=jnp.bfloat16,
     if cfg.format == "nm":
         Kc = K * cfg.n // cfg.m
         return NMPack(values=_sds((Kc, N), dtype),
-                      idx=_sds((Kc, N // cfg.block_n), jnp.int32),
+                      idx=_sds((N // cfg.block_n, Kc), jnp.int32),
                       K=K, N=N, n=cfg.n, m=cfg.m, g=cfg.block_n)
     if cfg.format == "combined":
         Kb, Nb = K // cfg.block_k, N // cfg.block_n
@@ -298,12 +298,16 @@ def sparsify_abstract(abstract_params, cfg) -> Any:
 # Forward
 # ---------------------------------------------------------------------------
 
-def apply_linear(x: Array, weight: Any, cfg: SparsityConfig = DENSE) -> Array:
+def apply_linear(x: Array, weight: Any, cfg: SparsityConfig = DENSE,
+                 name: Optional[str] = None) -> Array:
     """``x (..., K) @ weight (K, N) -> (..., N)`` for any format.
 
     Leading dims are flattened to the kernel's M dimension and restored.
     Kernel choice, backend fallback and block sizes are the dispatcher's
     job (``kernels.dispatch``) — this layer only normalizes shapes.
+    ``name`` is the weight's param name (``"wq"``, ``"w_out"``, ...): on
+    a tensor-parallel mesh it tells dispatch how the packed weight is
+    split (``distributed.sharding.shard_factors``).
     """
     from repro.kernels import dispatch  # local import: kernels pull in pallas
 
@@ -312,7 +316,7 @@ def apply_linear(x: Array, weight: Any, cfg: SparsityConfig = DENSE) -> Array:
     x2 = x.reshape(-1, K)
     if isinstance(weight, (BlockSparsePack, NMPack, CombinedPack,
                            LookaheadPack)):
-        out = dispatch.sparse_matmul(x2, weight, impl=cfg.impl)
+        out = dispatch.sparse_matmul(x2, weight, impl=cfg.impl, name=name)
         N = weight.N
     else:
         out = jnp.dot(x2, weight)

@@ -124,7 +124,7 @@ def pack_block_sparse(w: Array, bk: int, bn: int,
 class NMPack:
     """``n``-of-``m`` compressed K axis; positions shared over ``g`` columns."""
     values: Array      # (Kc, N)  — kept weights, Kc = K*n//m
-    idx: Array         # (Kc, N//g) int32 — position within each m-group [0, m)
+    idx: Array         # (N//g, Kc) int32 — position within each m-group [0, m)
     K: int
     N: int
     n: int
@@ -138,12 +138,12 @@ class NMPack:
 
     def src_rows(self) -> Array:
         """Absolute source K-row of each compressed row, per column group:
-        ``(Kc, N//g)``."""
-        kc = jnp.arange(self.Kc)[:, None]
+        ``(N//g, Kc)``."""
+        kc = jnp.arange(self.Kc)[None, :]
         return (kc // self.n) * self.m + self.idx
 
     def densify(self) -> Array:
-        src = self.src_rows()                                   # (Kc, Ng)
+        src = self.src_rows().T                                 # (Kc, Ng)
         dense = jnp.zeros((self.K, self.N), self.values.dtype)
         vals = self.values.reshape(self.Kc, self.N // self.g, self.g)
         col0 = jnp.arange(self.N // self.g) * self.g
@@ -173,7 +173,9 @@ def pack_nm(w: Array, n: int, m: int, g: int = 1) -> NMPack:
     vals = np.take_along_axis(wg, pos[:, :, :, None], axis=1)
     Kc = Kg * n
     values = vals.transpose(0, 1, 2, 3).reshape(Kc, Ng, g)[...].reshape(Kc, N)
-    idx = pos.reshape(Kc, Ng).astype(np.int32)
+    # group-major so one group's positions lie along lanes (the kernel
+    # reads a (groups, bkc) metadata tile per step)
+    idx = pos.reshape(Kc, Ng).T.astype(np.int32)
     return NMPack(values=jnp.asarray(values), idx=jnp.asarray(idx),
                   K=K, N=N, n=n, m=m, g=g)
 
@@ -238,7 +240,7 @@ def pack_combined(w: Array, n: int, m: int, bk: int, bn: int,
             tile = vals_np[j, t]                        # (bk, bn)
             sub = pack_nm(jnp.asarray(tile), n, m, g=bn)
             out_vals[j, t] = np.asarray(sub.values)
-            out_gidx[j, t] = np.asarray(sub.src_rows()[:, 0])
+            out_gidx[j, t] = np.asarray(sub.src_rows()[0])
     return CombinedPack(values=jnp.asarray(out_vals),
                         gidx=jnp.asarray(out_gidx),
                         indices=bsp.indices, counts=bsp.counts,

@@ -1,7 +1,7 @@
 """Mesh-agnostic sharding annotations for model code.
 
 ``constrain(x, *axes)`` is ``with_sharding_constraint`` that (a) no-ops
-outside any mesh context (smoke tests, single-host examples), (b) drops
+outside a ``jax.set_mesh`` context (smoke tests, single-host examples), (b) drops
 axes missing from the ambient mesh, and (c) drops axes that don't divide
 the dimension — so model code can state its *intended* layout once and
 run everywhere.  The named axes follow DESIGN.md §6: "data" (+"pod") for
@@ -51,35 +51,10 @@ def axis_size(name: str) -> int:
     return sizes.get(name, 1) if sizes else 1
 
 
-def _get_abstract_mesh():
-    """``jax.sharding.get_abstract_mesh`` moved between jax releases;
-    resolve whichever home this jax provides (None when unavailable)."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is None:
-        try:
-            from jax._src.mesh import get_abstract_mesh as getter
-        except ImportError:
-            return None
-    try:
-        return getter()
-    except Exception:
-        return None
-
-
 def _ambient_sizes() -> Optional[dict]:
-    am = _get_abstract_mesh()
-    if am is not None and not getattr(am, "empty", False) \
-            and tuple(getattr(am, "axis_names", ()) or ()):
-        return dict(am.shape)
-    # legacy `with mesh:` context (does not set the abstract mesh)
-    try:
-        from jax._src.mesh import thread_resources
-        pm = thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return dict(zip(pm.axis_names, pm.devices.shape))
-    except Exception:
-        pass
-    return None
+    """Axis sizes of the mesh entered with ``jax.set_mesh`` (None off-mesh)."""
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else dict(am.shape)
 
 
 def constrain(x: jax.Array, *axes: Axis) -> jax.Array:

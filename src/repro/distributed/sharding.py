@@ -38,17 +38,10 @@ from repro.models.config import ModelConfig
 
 
 def abstract_mesh(sizes: Tuple[int, ...], names: Tuple[str, ...]):
-    """Version-portable ``AbstractMesh`` constructor.
-
-    jax ≤ 0.4.x takes one ``((name, size), ...)`` pairs tuple; newer jax
-    takes ``(sizes, names)``.  Tests and the dry-run build their production
-    meshes through this so the repo runs on either API.
-    """
+    """``AbstractMesh`` of the given axis sizes and names — the production
+    meshes tests and the dry-run reason about without devices."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(sizes, names)           # new-style signature
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
+    return AbstractMesh(tuple(sizes), tuple(names))
 
 
 def mesh_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -155,12 +148,12 @@ def _param_rule(names: Tuple[str, ...], shape: Tuple[int, ...],
         if parent_row:
             return P(*lead, "model", dp)
         return P(*(None,) * nd)
-    if last == "idx" and nd >= 2:            # N:M (.., Kc, N//g)
+    if last == "idx" and nd >= 2:            # N:M (.., N//g, Kc)
         lead = (None,) * (nd - 2)
         if parent_col:
-            return P(*lead, None, "model")
-        if parent_row:
             return P(*lead, "model", None)
+        if parent_row:
+            return P(*lead, None, "model")
         return P(*(None,) * nd)
     if last == "indices" and nd >= 2:        # BSR (.., Nb, max_nnz)
         lead = (None,) * (nd - 2)

@@ -34,8 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 from repro.core.sparsity import BlockSparsePack
 
 
@@ -87,7 +85,7 @@ def bsr_matmul(x: jax.Array, pack: BlockSparsePack, *, bm: int = 128,
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, pack.N), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY)),
         interpret=interpret,

@@ -13,8 +13,13 @@ each surviving tile is already n:m-compressed to ``bkc = bk·n/m`` rows.
   * ``x``    (M, K)  block (bm, bk), index ``(i, indices[j, t])`` —
              lookahead skip (HBM traffic ∝ surviving tiles).
   * ``vals`` (Nb, max_nnz, bkc, bn) block (1, 1, bkc, bn).
-  * ``gidx`` (Nb, max_nnz, bkc) int32 — per-tile gather rows (VPU align
-             stage), shared across the strip's bn columns.
+  * ``gidx`` (Nb, max_nnz, bkc) int32 block (1, max_nnz, bkc), index
+             (j, 0, 0) — the strip's per-tile gather rows (VPU align
+             stage), shared across its bn columns and fetched once per
+             strip; step ``t`` reads row ``t``.
+
+The gather is the one-hot selection matmul of :mod:`nm_spmm`
+(``select_columns``): Mosaic lowers no lane gather.
 """
 
 from __future__ import annotations
@@ -26,9 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 from repro.core.sparsity import CombinedPack
+from repro.kernels.nm_spmm import mxu_dtype, select_columns
 
 
 def _kernel(idx_ref, cnt_ref, x_ref, v_ref, g_ref, o_ref, acc_ref):
@@ -41,11 +45,12 @@ def _kernel(idx_ref, cnt_ref, x_ref, v_ref, g_ref, o_ref, acc_ref):
 
     @pl.when(t < cnt_ref[j])
     def _mac():
-        src = g_ref[0, 0, :]                            # (bkc,)
-        xg = jnp.take(x_ref[...], src, axis=1)          # (bm, bkc)
-        acc_ref[...] += jax.lax.dot(xg.astype(jnp.float32),
-                                    v_ref[0, 0].astype(jnp.float32),
-                                    preferred_element_type=jnp.float32)
+        dt, prec = mxu_dtype(x_ref.dtype, v_ref.dtype)
+        src = g_ref[0, pl.ds(t, 1), :]                  # (1, bkc)
+        xg = select_columns(x_ref[...].astype(dt), src, x_ref.shape[1],
+                            prec)                        # (bm, bkc)
+        acc_ref[...] += jnp.dot(xg, v_ref[0, 0].astype(dt), precision=prec,
+                                preferred_element_type=jnp.float32)
 
     @pl.when(t == pl.num_programs(2) - 1)
     def _write():
@@ -72,8 +77,8 @@ def csa_matmul(x: jax.Array, pack: CombinedPack, *, bm: int = 128,
                          lambda i, j, t, idx, cnt: (i, idx[j, t])),
             pl.BlockSpec((1, 1, bkc, bn),
                          lambda i, j, t, idx, cnt: (j, t, 0, 0)),
-            pl.BlockSpec((1, 1, bkc),
-                         lambda i, j, t, idx, cnt: (j, t, 0)),
+            pl.BlockSpec((1, max_nnz, bkc),
+                         lambda i, j, t, idx, cnt: (j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, t, idx, cnt: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
@@ -82,8 +87,9 @@ def csa_matmul(x: jax.Array, pack: CombinedPack, *, bm: int = 128,
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, pack.N), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY)),
+        name="csa_matmul",
         interpret=interpret,
     )(pack.indices, pack.counts, x, pack.values, pack.gidx)

@@ -28,9 +28,14 @@ Three execution modes, resolved per call:
 Autotune: for the compiled path, a small sweep over ``bm``/``bkc``
 candidates is timed once per ``(kernel, M, K, N, dtype, pattern)`` key and
 persisted to a JSON cache (``REPRO_AUTOTUNE_CACHE`` or
-``~/.cache/repro/autotune.json``), so steady-state dispatch is a dict
-lookup.  ``ref`` mode never sweeps; ``interpret`` sweeps only when asked
-(tests use it to exercise the machinery on tiny shapes).
+``<repo>/.autotune.json``, see ``core.caches``), so steady-state dispatch
+is a dict lookup.  ``ref`` mode never sweeps; ``interpret`` sweeps only
+when asked (tests use it to exercise the machinery on tiny shapes).  A
+compiled sweep in which no candidate runs raises: there is no silent
+default on the chip.
+
+Every per-call degradation to the jnp oracle is counted
+(:func:`fallback_count`), so a run can prove it never left the kernels.
 """
 
 from __future__ import annotations
@@ -46,6 +51,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.caches import autotune_cache_path
 from repro.core.sparsity import (BlockSparsePack, CombinedPack, LookaheadPack,
                                  NMPack)
 from repro.kernels import ref as _ref
@@ -194,13 +200,6 @@ def resolve_mode(impl: str = "auto") -> str:
 # Autotune cache — JSON-persisted (kernel, shape, dtype, pattern) → blocks
 # ---------------------------------------------------------------------------
 
-def _default_cache_path() -> str:
-    return os.environ.get(
-        "REPRO_AUTOTUNE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                     "autotune.json"))
-
-
 class AutotuneCache:
     """Tiny persistent map: dispatch key → {"bm": .., "bkc": .., "us": ..}.
 
@@ -212,7 +211,7 @@ class AutotuneCache:
     """
 
     def __init__(self, path: Optional[str] = None):
-        self.path = path or _default_cache_path()
+        self.path = path or autotune_cache_path()
         self._data: Optional[Dict[str, dict]] = None
         self._lock = threading.Lock()
         self._warned = False
@@ -301,7 +300,9 @@ class KernelEntry:
 
     ``supports(desc, M)`` — structural eligibility (format + divisibility).
     ``run(x, weight, mode, blocks)`` — execute; ``blocks`` holds tuned
-    tile sizes (subset of ``tunable``).
+    tile sizes (subset of ``tunable``).  ``nm_spmm``, the one kernel a
+    row-parallel split serves, also takes ``out_dtype=`` (its partial
+    sums stay f32 until the cross-shard ``psum``).
     ``candidates(desc, M)`` — autotune sweep points, list of block dicts.
     """
     name: str
@@ -341,12 +342,14 @@ def _bm_candidates(M: int) -> List[int]:
 
 
 def _bkc_for(desc: SparsityDescriptor, cap: int = 128) -> int:
-    """Largest bkc ≤ cap dividing Kc and a multiple of n (nm_spmm rule)."""
+    """Largest lane-aligned bkc ≤ cap (a multiple of 128 and of n that
+    divides Kc), else the whole Kc — the tiles the TPU's (8, 128) block
+    rule admits for the metadata and activation blocks."""
     Kc = desc.K * desc.n // desc.m
-    for bkc in range(min(cap, Kc), desc.n, -1):
+    for bkc in range(min(cap, Kc) // 128 * 128, 0, -128):
         if Kc % bkc == 0 and bkc % desc.n == 0:
             return bkc
-    return desc.n        # Kc = (K//m)·n, so n always divides Kc
+    return Kc
 
 
 def _pad_m(x: Array, bm: int) -> Tuple[Array, int]:
@@ -359,21 +362,22 @@ def _pad_m(x: Array, bm: int) -> Tuple[Array, int]:
 
 # --- entries ---------------------------------------------------------------
 
-def _nm_run(x, pack, mode, blocks):
+def _nm_run(x, pack, mode, blocks, out_dtype=None):
     if mode == "ref":
         return _ref.nm_spmm_ref(x, pack)
     from repro.kernels.nm_spmm import nm_spmm
     bm = blocks.get("bm", 128)
     bkc = blocks.get("bkc") or _bkc_for(SparsityDescriptor.of(pack))
     xp, M = _pad_m(x, bm)
-    out = nm_spmm(xp, pack, bm=bm, bkc=bkc, interpret=(mode == "interpret"))
+    out = nm_spmm(xp, pack, bm=bm, bkc=bkc, out_dtype=out_dtype,
+                  interpret=(mode == "interpret"))
     return out[:M]
 
 
 def _nm_candidates(desc, M):
     cands = []
     for bm in _bm_candidates(M):
-        for cap in (64, 128, 256):
+        for cap in (128, 256, 512):
             bkc = _bkc_for(desc, cap)
             if {"bm": bm, "bkc": bkc} not in cands:
                 cands.append({"bm": bm, "bkc": bkc})
@@ -507,13 +511,11 @@ def _ref_decision(desc: SparsityDescriptor, entry_name: str,
 
 
 def select(weight: Any, M: int = 128, impl: str = "auto",
-           autotune: Optional[bool] = None,
            shard: Optional[Tuple[int, int]] = None) -> Decision:
     """Pick (kernel, mode, block sizes) for ``x (M, K) @ weight``.
 
-    Pure function of structure — no execution.  ``autotune=None`` means
-    "sweep on compiled-path cache miss"; ``False`` uses defaults on miss;
-    ``True`` forces a sweep even in interpret mode (tests).
+    Pure function of structure — no execution: cached blocks on a hit,
+    defaults on a miss.
 
     ``shard=(kf, nf)`` keys the decision at the SHARD-LOCAL problem
     (``K/kf``, ``N/nf``) — what each mesh slice actually computes under
@@ -539,7 +541,7 @@ def select(weight: Any, M: int = 128, impl: str = "auto",
                         descriptor=desc, reason="dense weight")
     if mode == "ref":
         return _ref_decision(desc, entry.name, "cpu fallback")
-    blocks = _blocks_for(entry, desc, M, mode, autotune)
+    blocks = _blocks_for(entry, desc, M, mode)
     return Decision(kernel=entry.name, mode=mode, blocks=blocks,
                     descriptor=desc,
                     reason="tpu" if mode == "compiled" else "forced kernel")
@@ -558,18 +560,14 @@ def _entry_for(desc: SparsityDescriptor, M: int) -> Optional[KernelEntry]:
 
 
 def _blocks_for(entry: KernelEntry, desc: SparsityDescriptor, M: int,
-                mode: str, autotune: Optional[bool]) -> Dict[str, int]:
-    cands = entry.candidates(desc, M)
-    default = _default_blocks(cands, M)
-    if mode == "interpret" and not autotune:
-        return default
+                mode: str) -> Dict[str, int]:
     key = cache_key(entry.name, M, desc, mode)
     hit = _CACHE.get(key)
     if hit is not None:
         return {k: v for k, v in hit.items() if k != "us"}
-    if autotune is False:
-        return default
-    return default          # sweep happens at call time (needs operands)
+    # on a miss: defaults (a compiled eager call sweeps at call time,
+    # since the sweep needs operands)
+    return _default_blocks(entry.candidates(desc, M), M)
 
 
 def _default_blocks(cands: List[dict], M: int) -> Dict[str, int]:
@@ -618,16 +616,24 @@ def tune(x: Array, weight: Any, mode: str = "compiled",
         return {k: v for k, v in hit.items() if k != "us"}
     cands = list(candidates) if candidates is not None \
         else entry.candidates(desc, x.shape[0])
-    best, best_us = None, float("inf")
+    best, best_us, last_err = None, float("inf"), None
     for blocks in cands:
         try:
             us = _time_call(lambda b=blocks: entry.run(x, weight, mode, b),
                             reps=reps)
-        except Exception:
-            continue                                # illegal tiling: skip
+        except Exception as e:                      # illegal tiling: skip
+            last_err = e
+            continue
         if us < best_us:
             best, best_us = dict(blocks), us
-    if best is None:                                # nothing ran: defaults
+    if best is None:
+        if mode == "compiled":
+            # on the chip a kernel that compiles for no candidate is a
+            # fault, not a tiling choice: surface it instead of defaults
+            raise RuntimeError(
+                f"{entry.name}: no block candidate compiled for "
+                f"M={x.shape[0]} {desc.pattern} ({len(cands)} tried)"
+            ) from last_err
         return _default_blocks(cands, x.shape[0])
     cache.put(key, {**best, "us": round(best_us, 1)})
     return best
@@ -637,41 +643,134 @@ def tune(x: Array, weight: Any, mode: str = "compiled",
 # Execution — the single entry point call sites use
 # ---------------------------------------------------------------------------
 
+_FALLBACKS = 0
+
+
+def fallback_count() -> int:
+    """Calls of :func:`sparse_matmul` that degraded to the jnp oracle
+    because the kernel raised (process lifetime)."""
+    return _FALLBACKS
+
+
 def sparse_matmul(x: Array, weight: Any, *, impl: str = "auto",
-                  autotune: Optional[bool] = None) -> Array:
+                  name: Optional[str] = None) -> Array:
     """``x (M, K) @ weight (K, N) -> (M, N)`` for dense or any pack.
 
     Selects the kernel from the weight's sparsity descriptor, resolves the
     execution mode for this backend, applies cached/tuned block sizes, and
     runs.  This is the only matmul entry point call sites should import.
+    ``name`` (the weight's param name) places a kernel call on a
+    tensor-parallel mesh, see :func:`_run_sharded`.
     """
-    decision = select(weight, M=x.shape[0], impl=impl, autotune=autotune)
+    global _FALLBACKS
+    decision = select(weight, M=x.shape[0], impl=impl)
     entry = _REGISTRY.get(decision.kernel)
     if entry is None:                               # "<kind>-ref" fallback
         return _ref_matmul(x, weight)
-    if decision.mode == "compiled" and decision.kernel != "dense" \
-            and not isinstance(x, jax.core.Tracer):
+    if decision.mode == "ref" or decision.kernel == "dense":
+        return entry.run(x, weight, decision.mode, decision.blocks)
+    split = _model_split(name, weight)
+    if split is not None:
+        run = lambda: _run_sharded(entry, x, weight, decision.mode,  # noqa
+                                   split, impl)
+    elif decision.mode == "compiled" and not isinstance(x, jax.core.Tracer) \
+            and _CACHE.get(cache_key(
+                entry.name, x.shape[0], decision.descriptor,
+                decision.mode)) is None:
         # eager compiled call with no cached tiling: sweep once, persist.
         # Under jit tracing the sweep can't time anything — cached blocks
         # (via `select`) or defaults apply instead.
-        key = cache_key(entry.name, x.shape[0], decision.descriptor,
-                        decision.mode)
-        if _CACHE.get(key) is None and autotune is not False:
-            blocks = tune(x, weight, mode=decision.mode)
-            return entry.run(x, weight, decision.mode, blocks)
-    if decision.mode == "ref" or decision.kernel == "dense":
-        return entry.run(x, weight, decision.mode, decision.blocks)
+        return entry.run(x, weight, decision.mode,
+                         tune(x, weight, mode=decision.mode))
+    else:
+        run = lambda: entry.run(x, weight, decision.mode,  # noqa: E731
+                                decision.blocks)
     try:
-        return entry.run(x, weight, decision.mode, decision.blocks)
+        return run()
     except Exception as e:
         # the Daghero-style posture: a sparse fast path may fail (bad
         # tiling, lowering bug, backend quirk) but the jnp oracle always
-        # runs — degrade this call rather than take the workload down
+        # runs — degrade this call rather than take the workload down,
+        # and count it so a chip run can refuse a degraded result
+        _FALLBACKS += 1
         warnings.warn(
             f"{decision.kernel} raised in {decision.mode} mode "
             f"({type(e).__name__}: {e}); falling back to the ref path",
             RuntimeWarning, stacklevel=2)
         return _ref_matmul(x, weight)
+
+
+# ---------------------------------------------------------------------------
+# Tensor parallelism: kernels under shard_map
+# ---------------------------------------------------------------------------
+
+def _model_split(name: Optional[str], weight: Any
+                 ) -> Optional[Tuple[int, int]]:
+    """``(kf, nf)`` split of ``weight`` over the ambient mesh's ``model``
+    axis (``jax.set_mesh``), or ``None`` off-mesh / unsplit.  A Mosaic
+    kernel cannot be partitioned by XLA, so every kernel call on a
+    multi-device model axis goes through :func:`_run_sharded`."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or dict(mesh.shape).get("model", 1) == 1:
+        return None
+    from repro.distributed.sharding import shard_factors
+    kf, nf = shard_factors((name,), mesh) if name else (1, 1)
+    kf = kf if weight.K % kf == 0 else 1
+    nf = nf if weight.N % nf == 0 else 1
+    return kf, nf
+
+
+def _pack_specs(weight: Any, kf: int, nf: int) -> Optional[Any]:
+    """shard_map in_specs of a pack split ``(kf, nf)`` over ``model`` —
+    the layout ``sharding.param_specs`` places (output-feature strips
+    for column-parallel weights; the compressed K axis for row-parallel
+    N:M), or ``None`` where the kernel cannot compute a slice."""
+    m = "model"
+    if (kf, nf) == (1, 1):
+        return None
+    if isinstance(weight, NMPack):
+        if weight.idx.shape[-2] % nf or weight.Kc % kf \
+                or (weight.Kc // kf) % weight.n:
+            return None
+        if nf > 1:
+            return dataclasses.replace(weight, values=jax.P(None, m),
+                                       idx=jax.P(m, None))
+        return dataclasses.replace(weight, values=jax.P(m, None),
+                                   idx=jax.P(None, m))
+    if isinstance(weight, (BlockSparsePack, CombinedPack)) and kf == 1 \
+            and weight.counts.shape[-1] % nf == 0:
+        return jax.tree.map(lambda _: jax.P(m), weight)
+    return None
+
+
+def _run_sharded(entry: KernelEntry, x: Array, weight: Any, mode: str,
+                 split: Tuple[int, int], impl: str) -> Array:
+    """Run ``entry`` per shard: column-parallel weights compute their
+    output features from a replicated ``x``; row-parallel ones contract
+    their K slice and ``psum`` the partial products.  A layout the
+    kernel cannot slice runs replicated on every device.  Block sizes
+    are keyed at the shard-local problem, as ``build_plans`` keys them."""
+    kf, nf = split
+    specs = _pack_specs(weight, kf, nf)
+    if specs is None:
+        kf = nf = 1
+        specs = jax.tree.map(lambda _: jax.P(), weight)
+    blocks = select(weight, M=x.shape[0], impl=impl,
+                    shard=(kf, nf)).blocks
+    local = {"K": weight.K // kf, "N": weight.N // nf}
+
+    def body(xs, ws):
+        ws = dataclasses.replace(ws, **local)
+        if kf == 1:
+            return entry.run(xs, ws, mode, blocks)
+        part = entry.run(xs, ws, mode, blocks, out_dtype=jnp.float32)
+        return jax.lax.psum(part, "model").astype(xs.dtype)
+
+    return jax.shard_map(
+        body, mesh=jax.sharding.get_abstract_mesh(),
+        in_specs=(jax.P(None, "model") if kf > 1 else jax.P(), specs),
+        out_specs=jax.P(None, "model") if nf > 1 else jax.P(),
+        check_vma=False)(x, weight)
 
 
 def _ref_matmul(x: Array, weight: Any) -> Array:

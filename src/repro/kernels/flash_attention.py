@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -141,7 +139,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         _make_kernel(bq, bk, Lk - Lq, s, causal, window, softcap),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY)),
         interpret=interpret,

@@ -62,10 +62,10 @@ def nm_spmm_ref(x: Array, pack: NMPack) -> Array:
     """
     M, K = x.shape
     Ng, g = pack.N // pack.g, pack.g
-    src = pack.src_rows()                              # (Kc, Ng)
-    xg = x[:, src]                                     # (M, Kc, Ng)
+    src = pack.src_rows()                              # (Ng, Kc)
+    xg = x[:, src]                                     # (M, Ng, Kc)
     vals = pack.values.reshape(pack.Kc, Ng, g)
-    out = jnp.einsum("mkj,kjg->mjg", xg.astype(jnp.float32),
+    out = jnp.einsum("mjk,kjg->mjg", xg.astype(jnp.float32),
                      vals.astype(jnp.float32))
     return out.reshape(M, pack.N).astype(x.dtype)
 

@@ -1,13 +1,16 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (architecture × shape × mesh)
 cell and extract the roofline terms (deliverables e + g).
 
-The two lines above MUST precede any other import (jax locks the device
-count on first init): the dry-run — and only the dry-run — sees 512
-placeholder CPU devices so ``make_production_mesh`` can build the real
-16×16 (single-pod) and 2×16×16 (multi-pod) meshes.
+The lines above MUST precede any other import (jax locks the platform
+and device count on first init): the dry-run — and only the dry-run —
+sees 512 placeholder CPU devices so ``make_production_mesh`` can build
+the real 16×16 (single-pod) and 2×16×16 (multi-pod) meshes.  The CPU pin
+is inherited by the per-cell child processes, so neither this process
+nor its children ever claim an attached TPU.
 
 Per cell this driver:
   1. builds the abstract params / optimizer / batch / cache pytrees
@@ -192,7 +195,7 @@ def run_cell(arch: str, cell_name: str, multi_pod: bool,
                                         microbatches, remat_policy, sparse)
     meta["profile"] = profile
     meta["sparse"] = sparse
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = step.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

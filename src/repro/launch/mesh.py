@@ -11,24 +11,39 @@ given whatever devices exist, it keeps the model axis fixed (the model
 must still fit) and grows/shrinks ``data`` — checkpoints reshard on
 restore (checkpoint/store.py), so elastic scaling is a relaunch, not a
 code change.
+
+Every mesh is built by :func:`make_mesh`: ``jax.make_mesh`` (devices in
+topology order) with ``Auto`` axis types.  JAX defaults to ``Explicit``
+axes, which ``with_sharding_constraint`` on bare ``PartitionSpec``s (the
+model's ``constrain`` annotations) refuses.  Enter a mesh with
+``jax.set_mesh(mesh)``; that is what the annotations and the sharded
+kernel calls (``kernels.dispatch``) read.
 """
 
 from __future__ import annotations
 
 import logging
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
-import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 log = logging.getLogger(__name__)
+
+
+def make_mesh(shape: Sequence[int] = (1, 1),
+              axes: Sequence[str] = ("data", "model"),
+              devices: Optional[Sequence] = None) -> Mesh:
+    """``jax.make_mesh`` with ``Auto`` axis types (see module doc)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_elastic_mesh(model_parallel: int = 16,
@@ -58,15 +73,13 @@ def make_elastic_mesh(model_parallel: int = 16,
         log.warning("make_elastic_mesh: model_parallel=%d does not divide "
                     "%d devices; using mesh shape data=%d x model=%d",
                     model_parallel, n, n // mp, mp)
-    return Mesh(np.array(devs[: (n // mp) * mp]).reshape(n // mp, mp),
-                ("data", "model"))
+    return make_mesh((n // mp, mp), devices=devs[: (n // mp) * mp])
 
 
 def make_host_mesh() -> Mesh:
     """1×1 mesh over the real local device (smoke tests, examples)."""
-    return Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
-                ("data", "model"))
+    return make_mesh(devices=jax.devices()[:1])
 
 
 def mesh_chips(mesh: Mesh) -> int:
-    return int(np.prod(list(mesh.shape.values())))
+    return mesh.devices.size

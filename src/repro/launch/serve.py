@@ -131,6 +131,8 @@ def main() -> int:
             + f" --xla_force_host_platform_device_count={args.devices}"
         ).strip()
 
+    from repro.core.caches import configure_caches
+    configure_caches()
     if args.dry_run:
         return _dry_run(args)
 
@@ -147,7 +149,7 @@ def main() -> int:
     mesh = make_elastic_mesh(model_parallel=args.model_parallel)
 
     rng = jax.random.key(args.seed)
-    with mesh:
+    with jax.set_mesh(mesh):
         params = MZ.init_model(rng, cfg)
     if args.checkpoint_dir:
         restored = restore_latest(args.checkpoint_dir,
@@ -318,7 +320,7 @@ def _dry_run(args) -> int:
         return mem
 
     programs = {}
-    with mesh:
+    with jax.set_mesh(mesh):
         prefill = loops.build_prefill_slot_step(
             cfg, mesh, scfg, abstract_params, abstract_cache,
             prompt_rows=scfg.prompt_pad, paged=True)

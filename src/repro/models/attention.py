@@ -99,9 +99,9 @@ def _project_qkv(params: Params, cfg: ModelConfig, x: Array,
     B, Lq, _ = x.shape
     src = x if kv_src is None else kv_src
     Lk = src.shape[1]
-    q = apply_linear(x, params["wq"], sparsity)
-    k = apply_linear(src, params["wk"], sparsity)
-    v = apply_linear(src, params["wv"], sparsity)
+    q = apply_linear(x, params["wq"], sparsity, name="wq")
+    k = apply_linear(src, params["wk"], sparsity, name="wk")
+    v = apply_linear(src, params["wv"], sparsity, name="wv")
     q = q.reshape(B, Lq, cfg.n_heads, cfg.head_dim)
     k = k.reshape(B, Lk, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(B, Lk, cfg.n_kv_heads, cfg.head_dim)
@@ -357,5 +357,5 @@ def attention(params: Params, cfg: ModelConfig, x: Array, positions: Array,
                 is_local=is_local, kv_len=kv_len)
     B, Lq = x.shape[0], x.shape[1]
     out = out.reshape(B, Lq, cfg.q_dim)
-    out = apply_linear(out, params["wo"], sparsity)
+    out = apply_linear(out, params["wo"], sparsity, name="wo")
     return out, new_cache

@@ -185,8 +185,10 @@ def _cross_kv(p_layer: Params, cfg: ModelConfig, enc_out: Array):
     """Project encoder output to one decoder layer's cross K/V."""
     from repro.core.sparse_linear import apply_linear
     B, Ls, _ = enc_out.shape
-    k = apply_linear(enc_out, p_layer["cross_attn"]["wk"], cfg.attn_sparsity)
-    v = apply_linear(enc_out, p_layer["cross_attn"]["wv"], cfg.attn_sparsity)
+    k = apply_linear(enc_out, p_layer["cross_attn"]["wk"], cfg.attn_sparsity,
+                     name="wk")
+    v = apply_linear(enc_out, p_layer["cross_attn"]["wv"], cfg.attn_sparsity,
+                     name="wv")
     k = k.reshape(B, Ls, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(B, Ls, cfg.n_kv_heads, cfg.head_dim)
     if cfg.qk_norm:
@@ -207,13 +209,15 @@ def _dec_step_body(p_layer, cfg: ModelConfig, x: Array, positions: Array,
     x = x + out
 
     h = L.rmsnorm(p_layer["ln_cross"], x, cfg.norm_eps)
-    q = apply_linear(h, p_layer["cross_attn"]["wq"], cfg.attn_sparsity)
+    q = apply_linear(h, p_layer["cross_attn"]["wq"], cfg.attn_sparsity,
+                     name="wq")
     q = q.reshape(B, Lq, cfg.n_heads, cfg.head_dim)
     if cfg.qk_norm:
         q = L.rmsnorm(p_layer["cross_attn"]["q_norm"], q, cfg.norm_eps)
     out = _sdpa(cfg, q, cross_k, cross_v, causal=False, window=None)
     out = out.reshape(B, Lq, cfg.q_dim)
-    out = apply_linear(out, p_layer["cross_attn"]["wo"], cfg.attn_sparsity)
+    out = apply_linear(out, p_layer["cross_attn"]["wo"], cfg.attn_sparsity,
+                       name="wo")
     x = x + out
 
     h = L.rmsnorm(p_layer["ln_mlp"], x, cfg.norm_eps)

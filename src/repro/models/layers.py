@@ -142,13 +142,13 @@ def init_mlp(rng: Array, d: int, ff: int, gated: bool = True,
 
 def mlp(params: Params, x: Array, gated: bool = True,
         sparsity: SparsityConfig = DENSE) -> Array:
-    h = apply_linear(x, params["w_in"], sparsity)
+    h = apply_linear(x, params["w_in"], sparsity, name="w_in")
     if gated:
-        g = apply_linear(x, params["w_gate"], sparsity)
+        g = apply_linear(x, params["w_gate"], sparsity, name="w_gate")
         h = jax.nn.silu(g.astype(jnp.float32)).astype(h.dtype) * h
     else:
         h = jax.nn.gelu(h.astype(jnp.float32)).astype(h.dtype)
-    return apply_linear(h, params["w_out"], sparsity)
+    return apply_linear(h, params["w_out"], sparsity, name="w_out")
 
 
 def init_dense(rng: Array, K: int, N: int, dtype=jnp.bfloat16) -> Array:

@@ -175,7 +175,7 @@ def mamba_block(params: Params, cfg: ModelConfig, x: Array, *,
     di, G, N, H = cfg.d_inner, cfg.ssm_groups, cfg.ssm_state, cfg.n_ssm_heads
     P = cfg.ssm_head_dim
 
-    zxbcdt = apply_linear(x, params["in_proj"], sparsity)
+    zxbcdt = apply_linear(x, params["in_proj"], sparsity, name="in_proj")
     z, xBC, dt = jnp.split(zxbcdt, [di, 2 * di + 2 * G * N], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32)
                          + params["dt_bias"][None, None, :])   # (b, l, H)
@@ -240,5 +240,6 @@ def mamba_block(params: Params, cfg: ModelConfig, x: Array, *,
     # gated RMSNorm (mamba2): norm(y * silu(z))
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
     y = L.rmsnorm(params["norm"], y, cfg.norm_eps)
-    out = apply_linear(y, params["out_proj"], sparsity)
+    out = apply_linear(y, params["out_proj"], sparsity,
+                       name="out_proj")
     return out, new_cache

@@ -284,7 +284,7 @@ class Engine(FaultTolerance):
             raise ValueError(
                 f"a registered prefix must be a whole number of pages: "
                 f"got {len(arr)} tokens with page_size={scfg.page_size}")
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             self._ensure_device_state()
             nodes, page_row = self._backend.register_prefix(arr)
             if page_row is not None:
@@ -579,7 +579,7 @@ class Engine(FaultTolerance):
         fully blocked).  Never raises on an injected/transient fault —
         the affected requests end in a terminal status instead."""
         events: List[TokenEvent] = []
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             self._ensure_device_state()
             self._apply_cancels()
             self._apply_deadlines()

@@ -210,7 +210,7 @@ class Trainer:
                 tree, step = restored
                 return tree["params"], tree["opt"], step
 
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             params = jax.jit(
                 lambda r: MZ.init_model(r, self.cfg),
                 out_shardings=pshard)(rng)
@@ -241,7 +241,7 @@ class Trainer:
             for attempt in range(self.tcfg.max_retries + 1):
                 t0 = time.perf_counter()
                 try:
-                    with self.mesh:
+                    with jax.set_mesh(self.mesh):
                         params, opt_state, metrics = step_fn(
                             params, opt_state, batch)
                     jax.block_until_ready(metrics["loss"])

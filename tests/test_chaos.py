@@ -16,6 +16,7 @@ from repro.models.config import LayerKind, ModelConfig
 from repro.serving import (TERMINAL_STATUSES, ChaosConfig, ChaosMonkey,
                            Engine, RequestStatus, ServeConfig)
 from repro.serving.chaos import AuditError, audit_engine
+from repro.launch.mesh import make_mesh
 
 TINY = ModelConfig(name="tiny", n_layers=2, d_model=64, vocab_size=512,
                    n_heads=4, n_kv_heads=2, d_ff=128, remat=False)
@@ -32,7 +33,7 @@ PROMPT_HI = np.arange(20, 36, dtype=np.int32)
 
 
 def mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh()
 
 
 @pytest.fixture(scope="module")

@@ -10,6 +10,7 @@ import pytest
 
 from repro.checkpoint import (CheckpointManager, load_checkpoint,
                               restore_latest, save_checkpoint)
+from repro.launch.mesh import make_mesh
 
 
 def tree(seed=0):
@@ -85,8 +86,8 @@ class TestResharding:
     def test_restore_onto_different_mesh(self):
         """Elasticity: save under one sharding, restore under another."""
         from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh_a = jax.make_mesh((1, 1), ("data", "model"))
-        mesh_b = jax.make_mesh((1,), ("data",))
+        mesh_a = make_mesh()
+        mesh_b = make_mesh((1,), ("data",))
         t = tree(7)
         with tempfile.TemporaryDirectory() as d:
             t_dev = jax.device_put(

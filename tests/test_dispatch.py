@@ -248,11 +248,33 @@ class TestModeOverride:
             dispatch._REGISTRY, "nm_spmm",
             dataclasses.replace(real, run=boom))
         x = rand(21, (64, 256))
+        before = dispatch.fallback_count()
         with pytest.warns(RuntimeWarning, match="falling back"):
             out = dispatch.sparse_matmul(x, packs["nm"], impl="kernel")
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref.nm_spmm_ref(x, packs["nm"])),
             rtol=2e-5, atol=1e-4)
+        assert dispatch.fallback_count() == before + 1
+
+    def test_compiled_tune_with_no_candidate_raises(self, packs,
+                                                     monkeypatch,
+                                                     isolated_cache):
+        """On the chip a sweep in which no candidate compiles is a fault:
+        it raises with the last error instead of returning defaults."""
+        real = dispatch._REGISTRY["nm_spmm"]
+
+        def boom(x, w, mode, blocks):
+            raise RuntimeError("tile explosion")
+        monkeypatch.setitem(
+            dispatch._REGISTRY, "nm_spmm",
+            dataclasses.replace(real, run=boom))
+        x = rand(22, (64, 256))
+        with pytest.raises(RuntimeError, match="no block candidate") as ei:
+            dispatch.tune(x, packs["nm"], mode="compiled", reps=1)
+        assert "tile explosion" in str(ei.value.__cause__)
+        # interpret sweeps keep the defaults (CPU tests of the machinery)
+        assert dispatch.tune(x, packs["nm"], mode="interpret", reps=1)
+        assert len(isolated_cache) == 0
 
 
 class TestPlan:

@@ -15,6 +15,7 @@ from conftest import reference_decode
 from repro import models as MZ
 from repro.models.config import LayerKind, ModelConfig
 from repro.serving import (Engine, RequestStatus, ServeConfig, Server)
+from repro.launch.mesh import make_mesh
 
 TINY = ModelConfig(name="tiny", n_layers=2, d_model=64, vocab_size=512,
                    n_heads=4, n_kv_heads=2, d_ff=128, remat=False)
@@ -35,7 +36,7 @@ BUDGETS = [5, 9, 3]
 
 
 def mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh()
 
 
 @pytest.fixture(scope="module")

@@ -59,6 +59,53 @@ def test_nm_spmm(n, m, M, K, N):
                                rtol=2e-5, atol=1e-4)
 
 
+@pytest.mark.parametrize("M", [8, 40])             # decode, spec verify
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_nm_spmm_decode(M, dtype):
+    """Served geometry: M = slots rows through a bm=8 tile, one column
+    tile holding several 128-wide groups (metadata block (groups, bkc))."""
+    x = rand(7, (M, 512), dtype)
+    wp, _ = pruning.n_m(rand(8, (512, 256)), 2, 4, group=128)
+    pack = sparsity.pack_nm(wp.astype(dtype), 2, 4, g=128)
+    out_k = ops.nm_matmul(x, pack, impl="kernel", bm=8, bkc=128)
+    np.testing.assert_allclose(
+        np.asarray(out_k, np.float32),
+        np.asarray(ref.nm_spmm_ref(x, pack), np.float32),
+        rtol=RTOL[dtype], atol=1e-2)
+
+
+def test_nm_spmm_column_tiles():
+    """bn < N: each (bkc, bn) step serves bn/g = 8 groups, and the
+    metadata block (8, bkc) walks the group axis with the column tile."""
+    from repro.kernels.nm_spmm import nm_spmm
+    x = rand(9, (8, 256))
+    wp, _ = pruning.n_m(rand(10, (256, 256)), 2, 4, group=16)
+    pack = sparsity.pack_nm(wp, 2, 4, g=16)
+    out_k = nm_spmm(x, pack, bm=8, bkc=128, bn=128, interpret=True)
+    np.testing.assert_allclose(np.asarray(out_k),
+                               np.asarray(ref.nm_spmm_ref(x, pack)),
+                               rtol=2e-5, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(out_k),
+                               np.asarray(x @ pack.densify()),
+                               rtol=2e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("x_ss", [0.0, 0.5])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_csa_matmul_decode(x_ss, dtype):
+    """M = 8 slots through a bm=8 tile; the strip's whole gidx block
+    (1, max_nnz, bkc) is fetched once and step t reads row t."""
+    x = rand(11, (8, 512), dtype)
+    wp, _ = pruning.combined_nm(rand(12, (512, 256)), x_ss, 2, 4,
+                                group=128, block=128)
+    pack = sparsity.pack_combined(wp.astype(dtype), 2, 4, 128, 128)
+    out_k = ops.combined_matmul(x, pack, impl="kernel", bm=8)
+    np.testing.assert_allclose(
+        np.asarray(out_k, np.float32),
+        np.asarray(ref.csa_matmul_ref(x, pack), np.float32),
+        rtol=RTOL[dtype], atol=1e-2)
+
+
 @pytest.mark.parametrize("x_ss", [0.0, 0.5])
 @pytest.mark.parametrize("M,K,N", [(128, 512, 128), (128, 256, 256)])
 def test_csa_matmul(x_ss, M, K, N):

@@ -15,13 +15,14 @@ from repro.optim import (AdamWConfig, adamw_init, adamw_update,
 from repro.optim.compression import compress_tree
 from repro.train import TrainConfig, Trainer
 from repro.train.trainer import _accumulate_grads, init_opt_state
+from repro.launch.mesh import make_mesh
 
 TINY = ModelConfig(name="tiny", n_layers=2, d_model=64, vocab_size=512,
                    n_heads=4, n_kv_heads=2, d_ff=128, remat=False)
 
 
 def mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh()
 
 
 class TestAdamW:
@@ -176,7 +177,7 @@ class TestTrainerEndToEnd:
             TINY, tcfg, mesh, jax.eval_shape(lambda: params), batch,
             masks=masks)
         opt = init_opt_state(params, tcfg)
-        with mesh:
+        with jax.set_mesh(mesh):
             for s in range(5):
                 params, opt, _ = step_fn(params, opt,
                                          make_batch(TINY, dcfg, s))

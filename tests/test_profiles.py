@@ -11,6 +11,7 @@ from repro import models as MZ
 from repro.core.sparse_linear import sparsify_abstract
 from repro.core.sparsity import NMPack
 from repro.distributed import annotate, sharding as SH
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture(autouse=True)
@@ -36,12 +37,12 @@ class TestAnnotate:
             annotate.set_sharding_mode("nope")
 
     def test_constrain_under_mesh_drops_nondividing(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh()
 
         def f(x):
             return annotate.constrain(x, "data", "model") * 2
 
-        with mesh:
+        with jax.set_mesh(mesh):
             out = jax.jit(f)(jnp.ones((3, 5)))
         assert out.shape == (3, 5)
 

@@ -12,13 +12,14 @@ from repro.kernels import dispatch
 from repro.core.sparse_linear import SparsityConfig, pack_params
 from repro.models.config import ModelConfig
 from repro.serving import ServeConfig, Server, sample_token
+from repro.launch.mesh import make_mesh
 
 TINY = ModelConfig(name="tiny", n_layers=2, d_model=64, vocab_size=512,
                    n_heads=4, n_kv_heads=2, d_ff=128, remat=False)
 
 
 def mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh()
 
 
 @pytest.fixture(scope="module")

@@ -61,7 +61,7 @@ def _cfg(fmt):
 
 
 def _params(cfg):
-    with make_host_mesh():
+    with jax.set_mesh(make_host_mesh()):
         params = MZ.init_model(jax.random.key(0), cfg)
     if cfg.mlp_sparsity.format != "dense" \
             or cfg.attn_sparsity.format != "dense":
@@ -150,14 +150,15 @@ class TestShardRules:
         assert SH.validate_specs(abstract, specs, mesh) == []
         wq = specs["layers"]["attn"]["wq"]          # col-parallel
         assert tuple(wq.values)[-1] == "model"
-        # raw rules (pre best_effort): idx shards its column groups
-        # aligned with the values' N axis; row-parallel flips to Kc
+        # raw rules (pre best_effort): idx (.., N//g, Kc) shards its
+        # column groups aligned with the values' N axis; row-parallel
+        # flips to Kc
         assert SH._param_rule(("layers", "attn", "wq", "idx"),
-                              (2, 16, 4), cfg, mesh) \
-            == P(None, None, "model")
-        assert SH._param_rule(("layers", "attn", "wo", "idx"),
-                              (2, 16, 4), cfg, mesh) \
+                              (2, 4, 16), cfg, mesh) \
             == P(None, "model", None)
+        assert SH._param_rule(("layers", "attn", "wo", "idx"),
+                              (2, 4, 16), cfg, mesh) \
+            == P(None, None, "model")
         assert SH._param_rule(("layers", "attn", "wo", "values"),
                               (2, 16, 64), cfg, mesh) \
             == P(None, "model", "data")

@@ -14,6 +14,7 @@ from repro.core.sparse_linear import (SparsityConfig, make_draft_params,
                                       pack_params)
 from repro.models.config import LayerKind, ModelConfig
 from repro.serving import ServeConfig, Server, build_spec_decode_loop
+from repro.launch.mesh import make_mesh
 
 TINY = ModelConfig(name="tiny", n_layers=2, d_model=64, vocab_size=512,
                    n_heads=4, n_kv_heads=2, d_ff=128, remat=False)
@@ -28,7 +29,7 @@ BASE = dict(slots=2, max_len=64, prompt_pad=8, max_new_tokens=16,
 
 
 def mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh()
 
 
 @pytest.fixture(scope="module")
@@ -171,7 +172,7 @@ class TestGreedyParity:
                  "left": jnp.full((2,), scfg.max_new_tokens, jnp.int32)}
         got = [[] for _ in range(2)]
         key = jax.random.key(0)
-        with mesh:
+        with jax.set_mesh(mesh):
             while not bool(jnp.all(state["done"])):
                 key, sk = jax.random.split(key)
                 cache, state, toks_blk, emit, _, _ = loop(
