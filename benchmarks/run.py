@@ -26,7 +26,6 @@ SECTIONS = [
     ("table2_int7", "benchmarks.bench_int7"),
     ("table3_resources", "benchmarks.bench_resources"),
     ("kernels", "benchmarks.bench_kernels"),
-    ("serving", "benchmarks.bench_serving"),
     ("roofline", "benchmarks.roofline"),
 ]
 

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import models as MZ
-from repro.core.sparse_linear import pack_params
+from repro.core.sparse_linear import SparsityConfig, pack_params
 from repro.distributed import sharding as SH
 from repro.kernels import dispatch
 from repro.kernels.dispatch import PACK_TYPES
@@ -40,6 +40,16 @@ from repro.models.config import ModelConfig
 
 
 SPEC_KS = (2, 4, 8)     # verify-block depths the spec rows serve at
+
+# the packed formats warmed, on a 2-layer d_model-64 model, and the paged
+# pool geometry (16-row pages, max_len 512 + 2*32) of the cache sweep
+SPARSITY = {
+    "nm": SparsityConfig(format="nm", n=2, m=4, block_n=64),
+    "combined": SparsityConfig(format="combined", sparsity=0.5, n=2, m=4,
+                               block_k=64, block_n=64),
+}
+WARM_PAGE = 16
+WARM_MAX_LEN = 512 + 2 * 32
 
 # shard-local warm geometry: wide enough that every model-parallel
 # extent below still divides the packed blocks (d_ff/8 = 64 = block_n)
@@ -101,6 +111,13 @@ def _layer_packs(params) -> List:
     return packs
 
 
+def _model(fmt: str):
+    cfg = ModelConfig(name=f"warm-{fmt}", n_layers=2, d_model=64,
+                      vocab_size=256, n_heads=4, n_kv_heads=2, d_ff=128,
+                      remat=False, mlp_sparsity=SPARSITY[fmt])
+    return cfg, pack_params(MZ.init_model(jax.random.key(0), cfg), cfg)
+
+
 def _tp_model(scfg_sp, d_ff):
     cfg = ModelConfig(name=f"warm-tp-{d_ff}", n_layers=1,
                       d_model=SD_D_MODEL, vocab_size=256,
@@ -150,16 +167,12 @@ def run(slots: int = 8, prompt_pad: int = 128, reps: int = 1,
     ``ServeConfig`` (defaults mirror its defaults) — the cache keys carry
     M, so a warm at the wrong geometry warms nothing.
     """
-    from benchmarks.bench_serving import (HET_MAX_LEN, HET_PAGE, SPARSITY,
-                                          _model)
     interpret = not dispatch.has_tpu()
     mode = "interpret" if interpret else "compiled"
     cache = dispatch.autotune_cache()
     entries = []
     t0 = time.time()
     for fmt in SPARSITY:
-        if SPARSITY[fmt] is None:
-            continue                      # dense: nothing to tune
         cfg, params = _model(fmt)
         for pack in _layer_packs(params):
             d = dispatch.SparsityDescriptor.of(pack)
@@ -178,8 +191,8 @@ def run(slots: int = 8, prompt_pad: int = 128, reps: int = 1,
     from repro.kernels.paged_attention import PagedKV
     cfg = ModelConfig(name="warm-paged", n_layers=1, d_model=64,
                       vocab_size=256, n_heads=4, n_kv_heads=2, d_ff=128)
-    mp = -(-HET_MAX_LEN // HET_PAGE)
-    pool = jnp.zeros((slots * mp + 1, HET_PAGE, cfg.n_kv_heads,
+    mp = -(-WARM_MAX_LEN // WARM_PAGE)
+    pool = jnp.zeros((slots * mp + 1, WARM_PAGE, cfg.n_kv_heads,
                       cfg.head_dim), jnp.bfloat16)
     # decode geometry (one query per slot) plus the speculative verify
     # geometries (slots*(k+1) queries) — plan keys carry M, so each
@@ -187,7 +200,7 @@ def run(slots: int = 8, prompt_pad: int = 128, reps: int = 1,
     for m in [slots] + [slots * (k + 1) for k in SPEC_KS]:
         kv = PagedKV(pool, pool,
                      jnp.zeros((m, mp), jnp.int32),
-                     jnp.full((m,), HET_PAGE, jnp.int32))
+                     jnp.full((m,), WARM_PAGE, jnp.int32))
         q = jnp.zeros((m, cfg.n_heads, cfg.head_dim), jnp.bfloat16)
         d = dispatch.SparsityDescriptor.of(kv)
         key = dispatch.cache_key("paged_attention", m, d, mode)
@@ -202,8 +215,6 @@ def run(slots: int = 8, prompt_pad: int = 128, reps: int = 1,
     # shard-local MLP geometry, so the sweep times real shard-sized
     # kernels; winners are recorded under the sharded plan's own keys.
     for fmt in SPARSITY:
-        if SPARSITY[fmt] is None:
-            continue
         _, full_params = _tp_model(SPARSITY[fmt], SD_FF)
         for ext in device_counts:
             if SD_FF % ext:
@@ -242,14 +253,14 @@ def run(slots: int = 8, prompt_pad: int = 128, reps: int = 1,
             if SD_HEADS % ext:
                 continue
             hk = SD_HEADS // ext
-            pool = jnp.zeros((slots * mp + 1, HET_PAGE, hk, hd),
+            pool = jnp.zeros((slots * mp + 1, WARM_PAGE, hk, hd),
                              jnp.bfloat16)
             kv = PagedKV(pool, pool, jnp.zeros((slots, mp), jnp.int32),
-                         jnp.full((slots,), HET_PAGE, jnp.int32))
+                         jnp.full((slots,), WARM_PAGE, jnp.int32))
             q = jnp.zeros((slots, SD_HEADS // ext, hd), jnp.bfloat16)
             dsh = dispatch.SparsityDescriptor(
-                kind="paged", K=mp * HET_PAGE, N=hd, dtype="bfloat16",
-                g=HET_PAGE, bk=mp, n=hk)
+                kind="paged", K=mp * WARM_PAGE, N=hd, dtype="bfloat16",
+                g=WARM_PAGE, bk=mp, n=hk)
             key = dispatch.cache_key("paged_attention", slots, dsh, mode)
             was_cached = cache.get(key) is not None
             blocks = dispatch.tune(q, kv, mode=mode, reps=reps,

@@ -316,16 +316,19 @@ def attention(params: Params, cfg: ModelConfig, x: Array, positions: Array,
         B, Lq = x.shape[0], x.shape[1]
         cp = jnp.asarray(cache_pos)
         cpb = cp if cp.ndim == 1 else jnp.broadcast_to(cp, (B,))
-        posn = cpb[:, None] + jnp.arange(Lq)[None, :]           # (B, Lq)
-        pages = jnp.take_along_axis(
-            pt, jnp.clip(posn // ps, 0, pt.shape[1] - 1), axis=1)
-        offs = posn % ps
-        ck = cache["kp"].at[pages, offs].set(k.astype(cache["kp"].dtype))
-        cv = cache["vp"].at[pages, offs].set(v.astype(cache["vp"].dtype))
-        new_cache = {"kp": ck, "vp": cv, "ptab": pt}
         Hk, D = k.shape[-2], k.shape[-1]
-        k = ck[pt].reshape(B, -1, Hk, D)
-        v = cv[pt].reshape(B, -1, Hk, D)
+        with jax.named_scope("kv_cache"):
+            posn = cpb[:, None] + jnp.arange(Lq)[None, :]       # (B, Lq)
+            pages = jnp.take_along_axis(
+                pt, jnp.clip(posn // ps, 0, pt.shape[1] - 1), axis=1)
+            offs = posn % ps
+            ck = cache["kp"].at[pages, offs].set(
+                k.astype(cache["kp"].dtype))
+            cv = cache["vp"].at[pages, offs].set(
+                v.astype(cache["vp"].dtype))
+            new_cache = {"kp": ck, "vp": cv, "ptab": pt}
+            k = ck[pt].reshape(B, -1, Hk, D)
+            v = cv[pt].reshape(B, -1, Hk, D)
         if ext > 1 and Hk % ext == 0:
             # head-parallel pool (cache_specs "heads"): keep the gathered
             # view sharded on its head axis so the page gather stays

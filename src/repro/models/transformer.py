@@ -98,40 +98,43 @@ def block(p: Params, cfg: ModelConfig, x: Array, positions: Array,
     from repro.distributed.annotate import batch_axes, constrain, seq_axis
     x = constrain(x, batch_axes(), seq_axis(), None)
     is_local = kind == int(LayerKind.ATTN_LOCAL)
-    h = L.rmsnorm(p["ln_attn"], x, cfg.norm_eps)
-    attn_out, new_cache = attention(
-        p["attn"], cfg, h, positions, is_local=is_local,
-        cache=cache, cache_pos=cache_pos, sparsity=cfg.attn_sparsity)
-    # constrain sub-block outputs back to the SP layout while still bf16:
-    # the row-parallel partial sums then reduce-scatter in bf16 instead of
-    # all-reducing the f32 upcast the residual add would otherwise hoist
-    attn_out = constrain(attn_out, batch_axes(), seq_axis(), None)
-    if cfg.post_norm:
-        attn_out = L.rmsnorm(p["ln_attn_post"], attn_out, cfg.norm_eps)
+    with jax.named_scope("attention"):
+        h = L.rmsnorm(p["ln_attn"], x, cfg.norm_eps)
+        attn_out, new_cache = attention(
+            p["attn"], cfg, h, positions, is_local=is_local,
+            cache=cache, cache_pos=cache_pos, sparsity=cfg.attn_sparsity)
+        # constrain sub-block outputs back to the SP layout while still
+        # bf16: the row-parallel partial sums then reduce-scatter in bf16
+        # instead of all-reducing the f32 upcast the residual add would
+        # otherwise hoist
+        attn_out = constrain(attn_out, batch_axes(), seq_axis(), None)
+        if cfg.post_norm:
+            attn_out = L.rmsnorm(p["ln_attn_post"], attn_out, cfg.norm_eps)
     x = x + attn_out
 
-    h = L.rmsnorm(p["ln_mlp"], x, cfg.norm_eps)
-    if cfg.n_experts:
-        if cfg.moe_impl == "grouped":
-            mlp_out, aux = M.moe_grouped(
-                p["moe"], cfg, h, sparsity=cfg.expert_sparsity,
-                capacity_factor=cfg.capacity_factor,
-                group_size=cfg.moe_group)
-        elif cfg.moe_impl == "sorted":
-            mlp_out, aux = M.moe_sorted(
-                p["moe"], cfg, h, sparsity=cfg.expert_sparsity,
-                capacity_factor=cfg.capacity_factor,
-                group_size=cfg.moe_group)
+    with jax.named_scope("mlp"):
+        h = L.rmsnorm(p["ln_mlp"], x, cfg.norm_eps)
+        if cfg.n_experts:
+            if cfg.moe_impl == "grouped":
+                mlp_out, aux = M.moe_grouped(
+                    p["moe"], cfg, h, sparsity=cfg.expert_sparsity,
+                    capacity_factor=cfg.capacity_factor,
+                    group_size=cfg.moe_group)
+            elif cfg.moe_impl == "sorted":
+                mlp_out, aux = M.moe_sorted(
+                    p["moe"], cfg, h, sparsity=cfg.expert_sparsity,
+                    capacity_factor=cfg.capacity_factor,
+                    group_size=cfg.moe_group)
+            else:
+                mlp_out, aux = M.moe(p["moe"], cfg, h,
+                                     sparsity=cfg.expert_sparsity)
         else:
-            mlp_out, aux = M.moe(p["moe"], cfg, h,
-                                 sparsity=cfg.expert_sparsity)
-    else:
-        mlp_out = L.mlp(p["mlp"], h, gated=cfg.mlp_gated,
-                        sparsity=cfg.mlp_sparsity)
-        aux = jnp.zeros((), jnp.float32)
-    mlp_out = constrain(mlp_out, batch_axes(), seq_axis(), None)
-    if cfg.post_norm:
-        mlp_out = L.rmsnorm(p["ln_mlp_post"], mlp_out, cfg.norm_eps)
+            mlp_out = L.mlp(p["mlp"], h, gated=cfg.mlp_gated,
+                            sparsity=cfg.mlp_sparsity)
+            aux = jnp.zeros((), jnp.float32)
+        mlp_out = constrain(mlp_out, batch_axes(), seq_axis(), None)
+        if cfg.post_norm:
+            mlp_out = L.rmsnorm(p["ln_mlp_post"], mlp_out, cfg.norm_eps)
     return x + mlp_out, new_cache, aux
 
 
@@ -183,9 +186,10 @@ def lm_hidden(params: Params, cfg: ModelConfig, inputs: Array,
         policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
                   if cfg.remat_policy == "dots" else None)
         body_fn = jax.checkpoint(body, policy=policy)
-    (x, aux), new_cache = jax.lax.scan(
-        body_fn, (x, jnp.zeros((), jnp.float32)),
-        (params["layers"], kinds, cache))
+    with jax.named_scope("layers"):
+        (x, aux), new_cache = jax.lax.scan(
+            body_fn, (x, jnp.zeros((), jnp.float32)),
+            (params["layers"], kinds, cache))
 
     x = L.rmsnorm(params["ln_final"], x, cfg.norm_eps)
     return x, new_cache, aux
@@ -206,7 +210,8 @@ def lm_apply(params: Params, cfg: ModelConfig, inputs: Array,
     if last_only:
         x = x[:, -1:]
     table = params.get("unembed", params["embed"])
-    logits = L.unembed(table, x, softcap=cfg.final_softcap)
+    with jax.named_scope("lm_head"):
+        logits = L.unembed(table, x, softcap=cfg.final_softcap)
     return logits, new_cache, aux
 
 

@@ -53,6 +53,7 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh
 
 from repro import models as MZ
@@ -93,10 +94,19 @@ class Engine(FaultTolerance):
     boundary by a per-slot prefill that writes only that slot's cache
     rows; in-flight slots never stall.
 
-    ``stats`` records per-chunk wall time and emitted-token counts (the
-    serving benchmark derives per-token latency percentiles from them);
+    ``stats`` records per-chunk wall time and pool pages in use;
     ``sync_count`` counts device→host transfers (the one-per-chunk
     contract); per-request TTFT lives on the :class:`Request` records.
+
+    Under ``jax.profiler`` every tick leaves host spans on the trace's
+    host plane (nothing is recorded when the profiler is off):
+    ``serve.step`` (arg ``tick``) holding ``serve.admit`` (cancellations,
+    deadlines, admission; one ``serve.prefill`` per prefill dispatch,
+    args ``uid`` and ``rows``), ``serve.launch`` (page-table upload, key
+    split, decode-loop dispatch), ``serve.fetch`` (the device→host
+    wait), ``serve.collect`` (guard, token distribution, retirement;
+    args ``pages`` in use and ``pool`` size) and, with a journal,
+    ``serve.journal`` (the chunk-boundary fsync).
     """
 
     def __init__(self, cfg: ModelConfig, mesh: Mesh, scfg: ServeConfig,
@@ -495,11 +505,14 @@ class Engine(FaultTolerance):
                 r.slot = i
                 r.set_status(RequestStatus.RUNNING)
                 self._slot_req[i] = r
-            self._key, sk = jax.random.split(self._key)
-            self._cache, self._state = wave(
-                self.params, {"tokens": jnp.asarray(prompts)}, self._cache,
-                jnp.asarray(valid), jnp.asarray(budgets),
-                jnp.asarray(self._temps), sk)
+            with TraceAnnotation("serve.prefill",
+                                 uid=",".join(str(r.uid) for r in take),
+                                 rows=scfg.prompt_pad):
+                self._key, sk = jax.random.split(self._key)
+                self._cache, self._state = wave(
+                    self.params, {"tokens": jnp.asarray(prompts)},
+                    self._cache, jnp.asarray(valid), jnp.asarray(budgets),
+                    jnp.asarray(self._temps), sk)
             self._stats["prefills"] += len(take)
             return
         while self.queue:
@@ -531,23 +544,26 @@ class Engine(FaultTolerance):
             start, cow = self._backend.prefill_plan(i)
             temp = (scfg.temperature if r.temperature is None
                     else r.temperature)
-            self._key, sk = jax.random.split(self._key)
-            self._cache, self._state = self._backend.prefill_step(
-                rows, start, cow)(
-                self.params, {"tokens": jnp.asarray(padded[:, start:])},
-                self._cache, self._state, jnp.asarray(i, jnp.int32),
-                jnp.asarray(r.remaining_new, jnp.int32),
-                jnp.asarray(temp, jnp.float32), sk,
-                *self._backend.prefill_args(i))
+            with TraceAnnotation("serve.prefill", uid=r.uid, rows=rows):
+                self._key, sk = jax.random.split(self._key)
+                self._cache, self._state = self._backend.prefill_step(
+                    rows, start, cow)(
+                    self.params, {"tokens": jnp.asarray(padded[:, start:])},
+                    self._cache, self._state, jnp.asarray(i, jnp.int32),
+                    jnp.asarray(r.remaining_new, jnp.int32),
+                    jnp.asarray(temp, jnp.float32), sk,
+                    *self._backend.prefill_args(i))
             self._temps[i] = temp
             r.slot = i
             r.set_status(RequestStatus.RUNNING)
             self._slot_req[i] = r
             self._stats["prefills"] += 1
 
-    def _collect(self, blk, emit, done, dt: float) -> List[TokenEvent]:
+    def _collect(self, blk, emit, done, dt: float,
+                 pages: int) -> List[TokenEvent]:
         """Distribute one fetched token block in emission order, stamp
-        TTFTs, record the chunk stats, and retire finished slots."""
+        TTFTs, record the chunk stats (wall time ``dt``, ``pages`` in
+        use), and retire finished slots."""
         scfg = self.scfg
         now = time.perf_counter()
         emitted: List[tuple] = []           # (request, index-in-output)
@@ -561,7 +577,7 @@ class Engine(FaultTolerance):
                     self._backend.note_commit(i)
                     emitted.append((r, len(r.out) - 1))
         self._stats["chunk_s"].append(dt)
-        self._stats["chunk_tokens"].append(len(emitted))
+        self._stats["chunk_pages"].append(pages)
         for i in range(scfg.slots):
             r = self._slot_req[i]
             if r is not None and done[i]:
@@ -579,33 +595,48 @@ class Engine(FaultTolerance):
         fully blocked).  Never raises on an injected/transient fault —
         the affected requests end in a terminal status instead."""
         events: List[TokenEvent] = []
-        with jax.set_mesh(self.mesh):
-            self._ensure_device_state()
-            self._apply_cancels()
-            self._apply_deadlines()
-            self._admit()
-            live = [i for i, r in enumerate(self._slot_req)
-                    if r is not None]
-            if live:
-                loop, extra = self._backend.begin_chunk(live)
-                self._key, sk = jax.random.split(self._key)
-                t0 = time.perf_counter()
-                f0 = self._fault_count()
-                fetched = self._run_chunk(live, loop, sk, extra)
-                dt = time.perf_counter() - t0
-                if fetched is None:     # unrecoverable fetch: the
-                    now = time.perf_counter()  # chunk's tokens are lost
-                    for i in live:
-                        self._quarantine(i, now)
-                else:
-                    blk, emit = self._guard_block(fetched[0], fetched[1])
-                    events = self._collect(blk, emit, fetched[2], dt)
-                self._note_chunk_health(self._fault_count() != f0)
-                self._backend.end_chunk(
-                    [i for i in live if self._slot_req[i] is not None])
-        self._tick += 1
-        if self.journal is not None:    # the chunk-boundary fsync runs
-            self.journal.record_tick(self, events)  # BEFORE delivery
+        with TraceAnnotation("serve.step", tick=self._tick):
+            with jax.set_mesh(self.mesh):
+                self._ensure_device_state()
+                with TraceAnnotation("serve.admit"):
+                    self._apply_cancels()
+                    self._apply_deadlines()
+                    self._admit()
+                live = [i for i, r in enumerate(self._slot_req)
+                        if r is not None]
+                if live:
+                    events = self._decode_chunk(live)
+            self._tick += 1
+            if self.journal is not None:    # the chunk-boundary fsync
+                with TraceAnnotation("serve.journal"):  # BEFORE delivery
+                    self.journal.record_tick(self, events)
+        return events
+
+    def _decode_chunk(self, live: List[int]) -> List[TokenEvent]:
+        """Launch one decode chunk over the ``live`` slots, make the
+        single fetch, and collect its tokens."""
+        with TraceAnnotation("serve.launch"):
+            loop, extra = self._backend.begin_chunk(live)
+            pages = self._backend.pages_in_use()
+            self._key, sk = jax.random.split(self._key)
+            t0 = time.perf_counter()
+            f0 = self._fault_count()
+            out = self._launch_chunk(live, loop, sk, extra)
+        fetched = self._fetch_chunk(out)
+        dt = time.perf_counter() - t0
+        events: List[TokenEvent] = []
+        with TraceAnnotation("serve.collect", pages=pages,
+                             pool=self.scfg.pool_pages):
+            if fetched is None:         # unrecoverable fetch: the
+                now = time.perf_counter()   # chunk's tokens are lost
+                for i in live:
+                    self._quarantine(i, now)
+            else:
+                blk, emit = self._guard_block(fetched[0], fetched[1])
+                events = self._collect(blk, emit, fetched[2], dt, pages)
+            self._note_chunk_health(self._fault_count() != f0)
+            self._backend.end_chunk(
+                [i for i in live if self._slot_req[i] is not None])
         return events
 
     # --- crash safety -------------------------------------------------

@@ -70,6 +70,7 @@ class CacheBackend(Protocol):
     def note_commit(self, slot: int) -> None: ...
     def end_chunk(self, live_slots: List[int]) -> None: ...
     def retire(self, slot: int) -> None: ...
+    def pages_in_use(self) -> int: ...
 
 
 class _BackendBase:
@@ -93,6 +94,10 @@ class _BackendBase:
         """(start row, needs-COW-copy) for the slot's pending prefill —
         (0, False) unless prefix sharing mapped resident pages."""
         return 0, False
+
+    def pages_in_use(self) -> int:
+        """Pool pages holding live rows (0 for layouts without a pool)."""
+        return 0
 
     def clear_programs(self) -> None:
         """Drop every cached jitted program so the next chunk/prefill
@@ -368,9 +373,8 @@ class PagedBackend(_BackendBase):
             page_row = np.zeros(scfg.max_pages, np.int32)
             for bb, nd in enumerate(nodes):
                 page_row[bb] = nd.page
-        in_use = (scfg.pool_pages - len(self.free_pages)
-                  - self.index.retained_pages)
-        self.stats["peak_pages"] = max(self.stats["peak_pages"], in_use)
+        self.stats["peak_pages"] = max(self.stats["peak_pages"],
+                                       self.pages_in_use())
         return nodes, page_row
 
     def release_prefix(self, nodes: List[Any]) -> None:
@@ -417,9 +421,14 @@ class PagedBackend(_BackendBase):
             page = self._take_page()
             self.ptab[i, base + len(self.slot_pages[i])] = page
             self.slot_pages[i].append(page)
-        in_use = self.scfg.pool_pages - len(self.free_pages) \
+        self.stats["peak_pages"] = max(self.stats["peak_pages"],
+                                       self.pages_in_use())
+
+    def pages_in_use(self) -> int:
+        """Pool pages taken by slots and pinned prefixes — free pages and
+        retained (refcount-zero, evictable) prefix pages excluded."""
+        return self.scfg.pool_pages - len(self.free_pages) \
             - (self.index.retained_pages if self.prefix_on else 0)
-        self.stats["peak_pages"] = max(self.stats["peak_pages"], in_use)
 
     def _ensure(self, i: int) -> None:
         """Cover the next decode chunk (allocation happens at chunk

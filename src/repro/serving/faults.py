@@ -49,6 +49,7 @@ from typing import Any, Dict, Optional
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.kernels import dispatch
 from repro.serving.state import Request, RequestStatus
@@ -148,14 +149,15 @@ class FaultTolerance:
         transient fetch error (counted in ``fetch_errors``) is retried
         up to twice; if every attempt fails the chunk's tokens are lost
         and the caller quarantines the live slots."""
-        for _ in range(3):
-            try:
-                out = self._device_fetch(tree)
-            except Exception:
-                self._stats["fetch_errors"] += 1
-                continue
-            self.sync_count += 1
-            return out
+        with TraceAnnotation("serve.fetch"):
+            for _ in range(3):
+                try:
+                    out = self._device_fetch(tree)
+                except Exception:
+                    self._stats["fetch_errors"] += 1
+                    continue
+                self.sync_count += 1
+                return out
         return None
 
     def _loop_args(self, key, extra) -> tuple:
@@ -165,20 +167,25 @@ class FaultTolerance:
         return (self.params, self._cache, self._state,
                 jnp.asarray(self._temps), key) + tuple(extra)
 
-    def _run_chunk(self, live, loop, key, extra):
-        """Invoke one decode chunk and make the single device→host fetch
-        — the speculative loop's drafted/accepted counters ride in the
-        same transfer.  A raising dispatch flips the engine into
-        degraded (ref) mode and retries the chunk once on the re-traced
-        loop; a retry failure propagates (the fault is not transient).
-        Returns ``None`` when the fetch itself is unrecoverable."""
+    def _launch_chunk(self, live, loop, key, extra):
+        """Invoke one decode chunk; returns the loop's outputs, still on
+        the device.  A raising dispatch flips the engine into degraded
+        (ref) mode and retries the chunk once on the re-traced loop; a
+        retry failure propagates (the fault is not transient)."""
         try:
-            out = self._invoke_loop(loop, self._loop_args(key, extra))
+            return self._invoke_loop(loop, self._loop_args(key, extra))
         except Exception as e:
             self._stats["kernel_failures"] += 1
             self._enter_degraded(f"decode dispatch raised: {e!r}")
             loop, extra = self._backend.begin_chunk(live)
-            out = self._invoke_loop(loop, self._loop_args(key, extra))
+            return self._invoke_loop(loop, self._loop_args(key, extra))
+
+    def _fetch_chunk(self, out):
+        """Adopt a launched chunk's cache and state and make the single
+        device→host fetch of its token block — the speculative loop's
+        drafted/accepted counters ride in the same transfer.  Returns
+        ``(tokens, emitted, done)``, or ``None`` when the fetch itself
+        is unrecoverable."""
         if self.scfg.spec:
             cache, state, tokens, emitted, dr, ac = out
             fetched = self._fetch_block(

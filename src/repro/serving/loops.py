@@ -147,18 +147,21 @@ def build_prefill_slot_step(cfg: ModelConfig, mesh: Mesh, scfg: ServeConfig,
 
     sspecs = _state_shardings(mesh)
     extra = (None,) if paged else ()
+    # the jitted function's name is the program's name in a profile
+    # (``jit_prefill_slot``), apart from every other serving program
     if paged:
-        def step(params, batch, cache, state, slot, budget, temp, key,
-                 page_row):
+        def prefill_slot(params, batch, cache, state, slot, budget, temp,
+                         key, page_row):
             return prefill(params, batch, cache, state, slot, budget,
                            temp, key, page_row)
     else:
-        def step(params, batch, cache, state, slot, budget, temp, key):
+        def prefill_slot(params, batch, cache, state, slot, budget, temp,
+                         key):
             return prefill(params, batch, cache, state, slot, budget,
                            temp, key)
 
     return jax.jit(
-        step,
+        prefill_slot,
         in_shardings=(SH.named(mesh, pspecs), SH.named(mesh, bspecs),
                       SH.named(mesh, cspecs), sspecs, None, None, None,
                       None) + extra,
@@ -356,10 +359,12 @@ def build_decode_loop(cfg: ModelConfig, mesh: Mesh, scfg: ServeConfig,
             done = done | (emit & ((tok == scfg.eos_token) | (left == 0)
                                    | (pos + 1 >= scfg.max_len)))
             if paged:
-                vcache = MZ.page_view(cache, view_pages)
+                with jax.named_scope("kv_cache"):
+                    vcache = MZ.page_view(cache, view_pages)
                 logits, vcache = MZ.decode_step(params, cfg, tok, vcache,
                                                 pos)
-                cache = MZ.unpage_view(vcache, cache)
+                with jax.named_scope("kv_cache"):
+                    cache = MZ.unpage_view(vcache, cache)
             else:
                 logits, cache = MZ.decode_step(params, cfg, tok, cache, pos)
             nxt = sample_token_slots(logits[:, :V],
@@ -377,7 +382,8 @@ def build_decode_loop(cfg: ModelConfig, mesh: Mesh, scfg: ServeConfig,
     sspecs = _state_shardings(mesh)
     if paged:
         def loop(params, cache, state, temps, key, ptab):
-            cache = MZ.set_page_table(cache, ptab)
+            with jax.named_scope("kv_cache"):
+                cache = MZ.set_page_table(cache, ptab)
             return scan_chunk(params, cache, state, temps, key)
     else:
         def loop(params, cache, state, temps, key):

@@ -141,7 +141,7 @@ class TokenEvent:
 
 
 def _fresh_stats() -> Dict[str, Any]:
-    return {"chunk_s": [], "chunk_tokens": [], "prefills": 0,
+    return {"chunk_s": [], "chunk_pages": [], "prefills": 0,
             "peak_pages": 0, "admission_waits": 0,
             "drafted": 0, "accepted": 0,
             "prefix_hits": 0, "shared_pages": 0, "cow_copies": 0,
@@ -158,12 +158,14 @@ class EngineStats:
     Replaces the ad-hoc dict/attribute surface (``stats["peak_pages"]``,
     ``cache_bytes()``, ``acceptance_rate()``): one frozen record with
     every counter the benchmarks and the launcher read, plus the
-    prefix-sharing tallies.  ``chunk_s`` / ``chunk_tokens`` are the
-    per-chunk wall times and emitted-token counts the latency
-    percentiles derive from.
+    prefix-sharing tallies.  ``chunk_s`` / ``chunk_pages`` hold one
+    entry per decode chunk: its wall time (launch to fetched block) and
+    the pool pages in use while it ran (paged; 0 for the monolithic
+    cache) — against ``ServeConfig.pool_pages`` the share of the pool
+    that holds live rows.
     """
     chunk_s: List[float]            # wall seconds per decode chunk
-    chunk_tokens: List[int]         # tokens emitted per decode chunk
+    chunk_pages: List[int]          # paged: pool pages in use per chunk
     prefills: int                   # prompt prefills dispatched
     peak_pages: int                 # paged: pool high-water mark
     admission_waits: int            # paged: admissions deferred for pages
@@ -240,13 +242,14 @@ def sample_token_slots(logits: Array, key: Array, temps: Array) -> Array:
     so a batch can mix greedy and sampled requests without either
     perturbing the other's stream.
     """
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    keys = _slot_keys(key, logits.shape[0])
-    safe = jnp.maximum(temps, 1e-6)[:, None]
-    sampled = jax.vmap(
-        lambda k, l: jax.random.categorical(k, l)
-    )(keys, logits / safe).astype(jnp.int32)
-    return jnp.where(temps > 0, sampled, greedy)
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        keys = _slot_keys(key, logits.shape[0])
+        safe = jnp.maximum(temps, 1e-6)[:, None]
+        sampled = jax.vmap(
+            lambda k, l: jax.random.categorical(k, l)
+        )(keys, logits / safe).astype(jnp.int32)
+        return jnp.where(temps > 0, sampled, greedy)
 
 
 def _slot_uniform(key: Array, n: int) -> Array:
@@ -282,7 +285,7 @@ class _StatsAccessor:
         d = e._stats
         return EngineStats(
             chunk_s=list(d["chunk_s"]),
-            chunk_tokens=list(d["chunk_tokens"]),
+            chunk_pages=list(d["chunk_pages"]),
             prefills=d["prefills"], peak_pages=d["peak_pages"],
             admission_waits=d["admission_waits"], drafted=d["drafted"],
             accepted=d["accepted"], prefix_hits=d["prefix_hits"],
