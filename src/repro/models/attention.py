@@ -243,13 +243,51 @@ _KV_CHUNK = 2048
 _Q_CHUNK = 2048
 
 
+def paged_write_read(cache: Params, k: Array, v: Array, pos: Array,
+                     layer: Optional[Array] = None
+                     ) -> Tuple[Params, Array, Array]:
+    """Scatter ``k``/``v`` (B, Lq, Hk, D), written at per-slot positions
+    ``pos`` (B,), into the pool pages the slots' page table names, then
+    gather the table back as a (B, max_pages*ps, Hk, D) logical view.
+
+    Row index == logical position, so the downstream mask/qpos math is
+    the monolithic one; rows past kv_len read whatever the mapped page
+    holds (null-page garbage included) and are masked exactly like
+    monolithic garbage rows.  Prefix sharing may map ONE page into
+    SEVERAL table rows: safe by the same math — gathers tolerate
+    duplicate page ids, and each slot's scatter lands at its own
+    positions (≥ its prompt rows), which always resolve to slot-private
+    pages, so a shared page is only ever read.
+
+    ``layer=None``: the pools are this layer's own (P, ps, Hk, D).  A
+    traced ``layer`` index addresses stacked (nl, P, ps, Hk, D) pools —
+    the layer scan carries them and this scatter updates them in place,
+    so a step touches only its own rows and the viewed pages, never a
+    whole layer's pool.  Returns ``(new_cache, k_view, v_view)``.
+    """
+    pt = cache["ptab"]                              # (B, max_pages)
+    kp, vp = cache["kp"], cache["vp"]
+    ps = kp.shape[-3]
+    B, Lq, Hk, D = k.shape
+    posn = pos[:, None] + jnp.arange(Lq)[None, :]   # (B, Lq)
+    pages = jnp.take_along_axis(
+        pt, jnp.clip(posn // ps, 0, pt.shape[1] - 1), axis=1)
+    at = (pages, posn % ps) if layer is None else (layer, pages, posn % ps)
+    kp = kp.at[at].set(k.astype(kp.dtype))
+    vp = vp.at[at].set(v.astype(vp.dtype))
+    view = pt if layer is None else (layer, pt)
+    return ({"kp": kp, "vp": vp, "ptab": pt},
+            kp[view].reshape(B, -1, Hk, D), vp[view].reshape(B, -1, Hk, D))
+
+
 def attention(params: Params, cfg: ModelConfig, x: Array, positions: Array,
               *, is_local=False,
               cache: Optional[Params] = None,
               cache_pos: Optional[Array] = None,
               cross_src: Optional[Array] = None,
               causal: Optional[bool] = None,
-              sparsity: SparsityConfig = DENSE
+              sparsity: SparsityConfig = DENSE,
+              layer: Optional[Array] = None
               ) -> Tuple[Array, Optional[Params]]:
     """Full attention layer: project → rope → (cache update) → sdpa → out.
 
@@ -259,7 +297,9 @@ def attention(params: Params, cfg: ModelConfig, x: Array, positions: Array,
         ``cache_pos`` (scalar) the write position; returns updated cache.
         A paged layer cache (``{"kp", "vp", "ptab"}``, see module
         docstring) is detected by its ``ptab`` key and routed through the
-        page-table scatter/gather instead.
+        page-table scatter/gather instead (:func:`paged_write_read`);
+        with a traced ``layer`` its pools are the stacked ones and the
+        returned cache holds them updated in place.
       * cross-attention: ``cross_src`` is the encoder output (no rope on kv,
         no causal mask).
       * ``causal=False`` with ``cross_src=None``: bidirectional
@@ -300,42 +340,18 @@ def attention(params: Params, cfg: ModelConfig, x: Array, positions: Array,
     new_cache = None
     kv_len = None
     if cache is not None and "ptab" in cache:
-        # paged cache: scatter the new rows into the pool pages named by
-        # the slot's page table, then gather the table back as a
-        # (B, max_pages*ps, Hk, D) logical view.  Row index == logical
-        # position, so the downstream mask/qpos math is unchanged; rows
-        # past kv_len read whatever the mapped page holds (null-page
-        # garbage included) and are masked exactly like monolithic
-        # garbage rows.  Prefix sharing may map ONE page into SEVERAL
-        # table rows: safe by the same math — gathers tolerate duplicate
-        # page ids, and each slot's scatter lands at its own positions
-        # (≥ its prompt rows), which always resolve to slot-private
-        # pages, so a shared page is only ever read.
-        pt = cache["ptab"]                          # (B, max_pages)
-        ps = cache["kp"].shape[1]
-        B, Lq = x.shape[0], x.shape[1]
         cp = jnp.asarray(cache_pos)
-        cpb = cp if cp.ndim == 1 else jnp.broadcast_to(cp, (B,))
-        Hk, D = k.shape[-2], k.shape[-1]
+        cpb = cp if cp.ndim == 1 else jnp.broadcast_to(cp, (x.shape[0],))
         with jax.named_scope("kv_cache"):
-            posn = cpb[:, None] + jnp.arange(Lq)[None, :]       # (B, Lq)
-            pages = jnp.take_along_axis(
-                pt, jnp.clip(posn // ps, 0, pt.shape[1] - 1), axis=1)
-            offs = posn % ps
-            ck = cache["kp"].at[pages, offs].set(
-                k.astype(cache["kp"].dtype))
-            cv = cache["vp"].at[pages, offs].set(
-                v.astype(cache["vp"].dtype))
-            new_cache = {"kp": ck, "vp": cv, "ptab": pt}
-            k = ck[pt].reshape(B, -1, Hk, D)
-            v = cv[pt].reshape(B, -1, Hk, D)
+            new_cache, k, v = paged_write_read(cache, k, v, cpb, layer)
+        Hk = k.shape[-2]
         if ext > 1 and Hk % ext == 0:
             # head-parallel pool (cache_specs "heads"): keep the gathered
             # view sharded on its head axis so the page gather stays
             # shard-local and attention runs collective-free per head
             k = constrain(k, batch_axes(), None, MODEL, None)
             v = constrain(v, batch_axes(), None, MODEL, None)
-        kv_len = cpb + Lq
+        kv_len = cpb + x.shape[1]
     elif cache is not None:
         # write the new k/v at cache_pos, attend over the whole cache.
         # cache_pos may be a scalar (shared write offset: prefill, wave

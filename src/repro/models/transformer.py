@@ -83,11 +83,13 @@ def init_lm(rng: Array, cfg: ModelConfig) -> Params:
 # ---------------------------------------------------------------------------
 
 def block(p: Params, cfg: ModelConfig, x: Array, positions: Array,
-          kind: Array, cache: Optional[Params], cache_pos
+          kind: Array, cache: Optional[Params], cache_pos,
+          layer: Optional[Array] = None
           ) -> Tuple[Array, Optional[Params], Array]:
     """Pre-norm block: x + attn(norm(x)); x + mlp(norm(x)).
 
     ``kind`` is a traced int32 (LayerKind); returns (x, new_cache, aux).
+    ``layer`` (traced) indexes stacked paged pools in ``cache``.
 
     The residual stream is sequence-sharded over the model axis
     (Megatron-SP): the per-layer remat stack shrinks |model|×, and the
@@ -102,7 +104,8 @@ def block(p: Params, cfg: ModelConfig, x: Array, positions: Array,
         h = L.rmsnorm(p["ln_attn"], x, cfg.norm_eps)
         attn_out, new_cache = attention(
             p["attn"], cfg, h, positions, is_local=is_local,
-            cache=cache, cache_pos=cache_pos, sparsity=cfg.attn_sparsity)
+            cache=cache, cache_pos=cache_pos, sparsity=cfg.attn_sparsity,
+            layer=layer)
         # constrain sub-block outputs back to the SP layout while still
         # bf16: the row-parallel partial sums then reduce-scatter in bf16
         # instead of all-reducing the f32 upcast the residual add would
@@ -154,7 +157,8 @@ def lm_hidden(params: Params, cfg: ModelConfig, inputs: Array,
     ``positions``: (B, L) int32, or (B, L, 3) for M-RoPE; defaults to
     ``cache_pos + arange(L)``.
     ``cache``: stacked (n_layers, B, S, Hk, D) k/v dict from
-    ``init_kv_cache``; ``cache_pos`` the scalar write offset.
+    ``init_kv_cache``, or the paged dict from ``init_paged_kv_cache``;
+    ``cache_pos`` the scalar or (B,) per-slot write offset.
     """
     if cfg.input_mode == "embeds" and inputs.ndim == 3:
         x = inputs.astype(L._dtype(cfg.dtype))
@@ -173,13 +177,27 @@ def lm_hidden(params: Params, cfg: ModelConfig, inputs: Array,
             positions = jnp.broadcast_to(positions[..., None], (B, Lq, 3))
 
     kinds = jnp.asarray(cfg.layer_kinds, jnp.int32)
+    # A paged cache's pools ride in the carry, stacked over layers, and
+    # each layer scatters into / gathers from them at its own index;
+    # only the small per-layer page tables are sliced.  As scanned
+    # xs/ys they would be sliced out and written back whole every layer
+    # (and copied again by the enclosing decode loop's carry).
+    layer_cache, pools, layer_ids = cache, None, None
+    if cache is not None and "ptab" in cache:
+        pools = {"kp": cache["kp"], "vp": cache["vp"]}
+        layer_cache, layer_ids = cache["ptab"], jnp.arange(kinds.shape[0])
 
     def body(carry, xs):
-        x, aux = carry
-        p_layer, kind, cache_layer = xs
+        x, aux, pools = carry
+        p_layer, kind, cache_layer, layer = xs
+        if pools is not None:
+            cache_layer = {**pools, "ptab": cache_layer}
         x, new_cache, aux_l = block(p_layer, cfg, x, positions, kind,
-                                    cache_layer, cache_pos)
-        return (x, aux + aux_l), new_cache
+                                    cache_layer, cache_pos, layer)
+        if pools is not None:
+            pools = {"kp": new_cache["kp"], "vp": new_cache["vp"]}
+            new_cache = None
+        return (x, aux + aux_l, pools), new_cache
 
     body_fn = body
     if cfg.remat and cache is None:
@@ -187,9 +205,11 @@ def lm_hidden(params: Params, cfg: ModelConfig, inputs: Array,
                   if cfg.remat_policy == "dots" else None)
         body_fn = jax.checkpoint(body, policy=policy)
     with jax.named_scope("layers"):
-        (x, aux), new_cache = jax.lax.scan(
-            body_fn, (x, jnp.zeros((), jnp.float32)),
-            (params["layers"], kinds, cache))
+        (x, aux, pools), new_cache = jax.lax.scan(
+            body_fn, (x, jnp.zeros((), jnp.float32), pools),
+            (params["layers"], kinds, layer_cache, layer_ids))
+    if pools is not None:
+        new_cache = {**pools, "ptab": cache["ptab"]}
 
     x = L.rmsnorm(params["ln_final"], x, cfg.norm_eps)
     return x, new_cache, aux

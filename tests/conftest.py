@@ -77,3 +77,13 @@ def reference_decode(params, cfg, prompt, max_new, eos, prompt_pad, max_len):
         tok = jnp.argmax(logits[:, :cfg.vocab_size], -1).astype(jnp.int32)
         pos += 1
     return out
+
+
+def shaped_ops(hlo: str, shape) -> list:
+    """``(name, opcode)`` of every instruction of an optimized HLO module,
+    fused computations included, whose output has ``shape`` (any element
+    type): what a program materializes at that shape."""
+    import re
+    dims = re.escape(",".join(str(int(d)) for d in shape))
+    return re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[" + dims
+                      + r"\]\S* ([\w\-]+)\(", hlo, re.M)

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import reference_decode
+from conftest import reference_decode, shaped_ops
 from repro import models as MZ
 from repro.core.sparse_linear import SparsityConfig, pack_params
 from repro.kernels import dispatch, ref
@@ -291,3 +291,189 @@ class TestPagedKernel:
             assert len(rows) == 1
             assert rows[0]["pattern"] == "paged8x8"
             assert rows[0]["blocks"] == {"ps": 8, "pages": 8}
+
+
+# ---------------------------------------------------------------------------
+# The stacked pool rides in the layer scan's carry, updated in place
+# ---------------------------------------------------------------------------
+
+def _paged_programs(scfg, view_pages):
+    """The paged decode loop and per-slot prefill at ``TINY``, lowered
+    and compiled for the host; returns their optimized HLO and the
+    stacked pool's shape."""
+    from repro.serving import loops
+    from repro.serving.state import init_decode_state
+    mesh = mesh11()
+    ap = jax.eval_shape(lambda: MZ.init_model(jax.random.key(0), TINY))
+    ac = jax.eval_shape(lambda: MZ.init_cache(
+        TINY, scfg.slots, scfg.max_len, page_size=scfg.page_size,
+        num_pages=scfg.pool_pages))
+    state = jax.eval_shape(lambda: init_decode_state(scfg.slots))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    i32 = jax.ShapeDtypeStruct((), np.int32)
+    with jax.set_mesh(mesh):
+        loop = loops.build_decode_loop(TINY, mesh, scfg, ap, ac, paged=True,
+                                       view_pages=view_pages)
+        loop_hlo = loop.lower(
+            ap, ac, state, jax.ShapeDtypeStruct((scfg.slots,), np.float32),
+            key, jax.ShapeDtypeStruct((scfg.slots, scfg.max_pages),
+                                      np.int32)).compile().as_text()
+        prefill = loops.build_prefill_slot_step(TINY, mesh, scfg, ap, ac,
+                                                prompt_rows=8, paged=True)
+        prefill_hlo = prefill.lower(
+            ap, {"tokens": jax.ShapeDtypeStruct((1, 8), np.int32)}, ac,
+            state, i32, i32, jax.ShapeDtypeStruct((), np.float32), key,
+            jax.ShapeDtypeStruct((scfg.max_pages,), np.int32)
+        ).compile().as_text()
+    return {"loop": loop_hlo, "prefill": prefill_hlo}, ac["kp"].shape
+
+
+class TestPoolInPlace:
+    """A paged step writes its rows into the stacked pool where they lie
+    and reads only the viewed pages: no program copies, zero-fills,
+    slices or writes back a whole pool."""
+
+    def test_no_whole_pool_copy_slice_or_write_back(self):
+        """The compiled paged decode loop and per-slot prefill hold no
+        ``copy``, ``broadcast`` or ``dynamic-update-slice`` whose output
+        is the stacked ``(n_layers, P+1, ps, Hk, D)`` pool, and no
+        instruction at all whose output is one layer's ``(P+1, ps, Hk,
+        D)`` pool.  The pool is 8x the pages the loop views.
+
+        A layer scan that passes the pool as ``xs``/``ys`` fails this:
+        its compiled loop copies both stacked pools for the carry,
+        zero-fills a stacked ``ys`` buffer, slices each layer's pool out
+        and writes it back with a whole-pool ``dynamic-update-slice``."""
+        scfg = ServeConfig(slots=2, max_len=32, prompt_pad=8,
+                           max_new_tokens=8, decode_chunk=4, eos_token=-1,
+                           page_size=4, num_pages=32, page_view_chunk=2)
+        hlo, pool = _paged_programs(scfg, view_pages=2)
+        assert pool[1] >= 4 * scfg.slots * 2
+        for name, text in hlo.items():
+            whole = [op for op in shaped_ops(text, pool)
+                     if op[1] in ("copy", "broadcast",
+                                  "dynamic-update-slice")]
+            assert whole == [], (name, whole)
+            assert shaped_ops(text, pool[1:]) == [], name
+
+    def test_pool_rows_match_monolithic_rows(self, params):
+        """After a per-slot prefill of two slots and one decode chunk,
+        every ``(slot, position)`` row gathered through the page table
+        equals the monolithic cache's row, layer by layer, bit for bit.
+        A third, frozen slot (retired: done, all-null table row) sits at
+        a position past the view, so its write clips into its table's
+        nulled tail: it lands in page 0, and every page outside the live
+        tables and page 0 keeps its stale contents."""
+        from repro.serving import loops
+        from repro.serving.state import init_decode_state
+        ps, slots, rows, frozen_pos = 4, 3, 8, 22
+        base = dict(slots=slots, max_len=32, prompt_pad=rows,
+                    max_new_tokens=8, decode_chunk=4, eos_token=-1)
+        mono_cfg = ServeConfig(**base)
+        paged_cfg = ServeConfig(**base, page_size=ps, page_view_chunk=4)
+        # scattered, non-contiguous pages: 3 a slot hold rows 0..11
+        table = np.zeros((slots, paged_cfg.max_pages), np.int32)
+        table[0, :3] = [5, 17, 2]
+        table[1, :3] = [9, 3, 22]
+        live_pages = set(table[:2, :3].ravel().tolist())
+        mesh = mesh11()
+        ap = jax.eval_shape(lambda: params)
+        mono = MZ.init_cache(TINY, slots, 32)
+        paged = MZ.init_cache(TINY, slots, 32, page_size=ps)
+        # stale pool contents: whatever earlier requests left behind
+        paged = {**paged,
+                 "kp": jax.random.normal(jax.random.key(7),
+                                         paged["kp"].shape, jnp.bfloat16),
+                 "vp": jax.random.normal(jax.random.key(8),
+                                         paged["vp"].shape, jnp.bfloat16)}
+        stale = {k: np.asarray(paged[k]) for k in ("kp", "vp")}
+        prompts = jax.random.randint(jax.random.key(1), (2, rows), 1, 500)
+        temps = jnp.zeros((slots,), jnp.float32)
+        key = jax.random.key(3)
+        out = {}
+        with jax.set_mesh(mesh):
+            for kind, scfg, cache in (("mono", mono_cfg, mono),
+                                      ("paged", paged_cfg, paged)):
+                is_paged = kind == "paged"
+                ac = jax.eval_shape(lambda: cache)
+                prefill = loops.build_prefill_slot_step(
+                    TINY, mesh, scfg, ap, ac, prompt_rows=rows,
+                    paged=is_paged)
+                state = init_decode_state(slots)
+                for s in range(2):
+                    extra = (jnp.asarray(table[s]),) if is_paged else ()
+                    cache, state = prefill(
+                        params, {"tokens": prompts[s:s + 1]}, cache, state,
+                        jnp.int32(s), jnp.int32(8), jnp.float32(0.0), key,
+                        *extra)
+                state = {**state,
+                         "tok": state["tok"].at[2].set(11),
+                         "pos": state["pos"].at[2].set(frozen_pos)}
+                loop = loops.build_decode_loop(
+                    TINY, mesh, scfg, ap, ac, paged=is_paged,
+                    view_pages=4 if is_paged else None)
+                extra = (jnp.asarray(table),) if is_paged else ()
+                cache, state, _, _ = loop(params, cache, state, temps, key,
+                                          *extra)
+                out[kind] = jax.tree.map(np.asarray, cache)
+        end = rows + scfg.decode_chunk
+        assert np.asarray(state["pos"])[:2].tolist() == [end, end]
+        for name in ("k", "v"):
+            pool, rows_m = out["paged"][name + "p"], out["mono"][name]
+            for s in range(2):
+                pos = np.arange(end)
+                got = pool[:, table[s, pos // ps], pos % ps]
+                np.testing.assert_array_equal(got, rows_m[:, s, :end])
+            # the frozen slot's write went to the null page: its layer-0
+            # row (a function of its token and position alone) is there
+            np.testing.assert_array_equal(
+                pool[0, 0, frozen_pos % ps], rows_m[0, 2, frozen_pos])
+            untouched = [p for p in range(1, pool.shape[1])
+                         if p not in live_pages]
+            np.testing.assert_array_equal(pool[:, untouched],
+                                          stale[name + "p"][:, untouched])
+
+
+_SHARDED_PARITY = r"""
+import jax, numpy as np
+from repro import models as MZ
+from repro.launch.mesh import make_elastic_mesh, make_host_mesh
+from repro.models.config import ModelConfig
+from repro.serving import Engine, ServeConfig
+cfg = ModelConfig(name="tiny", n_layers=2, d_model=64, vocab_size=512,
+                  n_heads=4, n_kv_heads=2, d_ff=128, remat=False)
+with jax.set_mesh(make_host_mesh()):
+    params = MZ.init_model(jax.random.key(0), cfg)
+r = np.random.default_rng(1)
+prompts = [r.integers(1, 500, size=int(r.integers(4, 20))).astype(np.int32)
+           for _ in range(4)]
+for spec in (0, 2):
+    scfg = ServeConfig(slots=3, max_len=64, prompt_pad=24, max_new_tokens=10,
+                       decode_chunk=4, eos_token=-1, page_size=4,
+                       page_view_chunk=2, spec_k=spec)
+    one = Engine(cfg, make_host_mesh(), scfg, params)
+    two = Engine(cfg, make_elastic_mesh(model_parallel=2), scfg, params)
+    info = two._backend.shard_info()
+    assert info["model_extent"] == 2 and info["kv_mode"] == "heads", info
+    a, b = one.generate(prompts), two.generate(prompts)
+    assert a == b, (spec, a, b)
+    assert two.audit()["pool_leaves"] == 2
+print("OK")
+"""
+
+
+def test_head_sharded_pool_serves_bit_identically():
+    """On a 1x2 mesh (two CPU devices, in a process of its own) the pool
+    is head-sharded and the paged Engine — plain and speculative decode
+    — serves the same greedy tokens as one device."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
+           "PYTHONPATH": os.path.join(root, "src")}
+    done = subprocess.run([sys.executable, "-c", _SHARDED_PARITY], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-4000:]
+    assert done.stdout.strip().endswith("OK")

@@ -127,3 +127,63 @@ def test_sharded_kernel_compiles(mesh4, scfg, name, K, N):
     collectives = set(re.findall(r"all-reduce|all-gather", hlo))
     row_nm = name == "w_out" and scfg is NM
     assert collectives == ({"all-reduce"} if row_nm else set())
+
+
+@pytest.mark.parametrize("program", ["decode_loop", "prefill_slot"])
+def test_paged_programs_update_the_pool_in_place(topo, program):
+    """The ``nm-chat`` serving programs at Qwen3-0.6B's 28 layers on one
+    chip (16 slots, a 1,393-page pool of 16 rows, a 32-page view): the
+    compiled decode loop and per-slot prefill write the stacked pool in
+    place — no ``copy``, ``broadcast``, ``dynamic-slice`` or
+    ``dynamic-update-slice`` outputs a whole stacked pool, and nothing
+    outputs one layer's pool.  A layer scan that passes the pool as
+    ``xs``/``ys`` leaves two whole-pool copies and two whole-pool
+    ``dynamic-update-slice`` write-backs here, and slices a 45.6 MB
+    layer pool out twice every layer."""
+    from conftest import shaped_ops
+    from repro import models as MZ
+    from repro.models.config import ModelConfig
+    from repro.serving import ServeConfig, loops
+    from repro.serving.state import init_decode_state
+    cfg = ModelConfig(name="qwen3-0.6b", n_layers=28, d_model=D_MODEL,
+                      vocab_size=151936, n_heads=16, n_kv_heads=8,
+                      head_dim=128, d_ff=D_FF, qk_norm=True, rope_theta=1e6,
+                      norm_eps=1e-6, tie_embeddings=True, remat=False)
+    scfg = ServeConfig(slots=16, max_len=1392, prompt_pad=448,
+                       max_new_tokens=960, decode_chunk=16, eos_token=-1,
+                       page_size=16, prompt_buckets=32, page_view_chunk=8)
+    mesh = make_mesh((1, 1), devices=topo.devices[:1])
+    rep = NamedSharding(mesh, P())
+
+    def placed(fn):
+        return _placed(jax.eval_shape(fn), rep)
+    ap = placed(lambda: MZ.init_model(jax.random.key(0), cfg))
+    ac = placed(lambda: MZ.init_cache(cfg, scfg.slots, scfg.max_len,
+                                      page_size=scfg.page_size,
+                                      num_pages=scfg.pool_pages))
+    state = placed(lambda: init_decode_state(scfg.slots))
+    key = placed(lambda: jax.random.key(0))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+    with jax.set_mesh(mesh):
+        if program == "decode_loop":
+            fn = loops.build_decode_loop(cfg, mesh, scfg, ap, ac, paged=True,
+                                         view_pages=32)
+            args = (ap, ac, state, sds((16,), jnp.float32), key,
+                    sds((16, scfg.max_pages), jnp.int32))
+        else:
+            fn = loops.build_prefill_slot_step(cfg, mesh, scfg, ap, ac,
+                                               prompt_rows=160, paged=True)
+            i32 = sds((), jnp.int32)
+            args = (ap, {"tokens": sds((1, 160), jnp.int32)}, ac, state,
+                    i32, i32, sds((), jnp.float32), key,
+                    sds((scfg.max_pages,), jnp.int32))
+        hlo = fn.lower(*args).compile().as_text()
+    pool = ac["kp"].shape
+    assert pool == (28, 1393, 16, 8, 128)
+    whole = [op for op in shaped_ops(hlo, pool)
+             if op[1] in ("copy", "broadcast", "dynamic-slice",
+                          "dynamic-update-slice")]
+    assert whole == []
+    assert shaped_ops(hlo, pool[1:]) == []
