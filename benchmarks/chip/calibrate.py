@@ -10,10 +10,10 @@ benchmark run.  Needs the chip, like ``run.py``.
 
   python3 benchmarks/chip/calibrate.py --workload nm-chat \
       --sweep serve.slots+clients=8,16,32 --seconds 20
-      One set of weights, then one window per value of one or more mix
-      keys (joined by ``+``) on a fresh engine: requests sent and finished, the end-to-end metrics,
-      decode chunk time, peak memory so far, and the program's gap
-      numbers on the cell's sample.
+      One window per value of one or more mix keys (joined by ``+``), each
+      on an engine built anew from the seed: requests sent and finished,
+      the end-to-end metrics, decode chunk time, each chip's peak memory
+      so far, and the program's gap numbers on the cell's sample.
 
 ``--set key=value`` (repeatable) overrides a mix key first, for example
 ``--set prompt_tokens.multiple_of=1`` for prompts of any length.
@@ -66,24 +66,17 @@ def seeds_mode(cell, seeds, seconds, log, peaks):
             "metrics": {k: v["value"] for k, v in res["metrics"].items()},
             "window_compiles": rec["window_compiles"],
             "reference_s": rec["reference_s"],
-            "memory_peak_bytes": res["memory_peak_bytes"]}), flush=True)
+            "memory_peak_bytes": res["memory_peak_bytes"],
+            "memory_peaks": res["memory_peaks"]}), flush=True)
 
 
 def sweep_mode(cell, keys, values, seconds, seed, log):
-    import jax
-    from repro.launch.mesh import make_host_mesh
-    from repro.serving import Engine
-    ref = harness.plugin(cell, "reference", cell.cfg["model_type"])
-    adapter = harness.plugin(cell, "adapters", cell.cfg["model_type"])
-    mcfg = adapter.model_config(cell.cfg)
-    params = adapter.program_params(ref.make_weights(cell.cfg, seed), mcfg)
     key = "+".join(keys)
     for value in values:
         for k in keys:
             set_key(cell, k, value)
         t = time.monotonic()
-        eng = Engine(mcfg, make_host_mesh(),
-                     harness.serve_config(cell, ref.seed32(seed)), params)
+        eng = harness.build_engine(cell, seed)
         harness.warm_up(eng, cell)
         eng.reset_stats()
         warm_s = time.monotonic() - t
@@ -96,17 +89,15 @@ def sweep_mode(cell, keys, values, seconds, seed, log):
         ctx = harness.context(cell, rec)
         metrics = harness.read_metrics(cell, cell.end_to_end, ctx)
         chunk = rec["chunk_s"]
-        mem = (jax.devices()[0].memory_stats() or {}).get(
-            "peak_bytes_in_use")
+        mesh = eng.mesh
+        mem = harness.memory_peaks(mesh)
         sample = harness.check_sample(win, seed,
                                       cell.limits["sample_tokens"])
         for r in win["recs"].values():
             r.handle = None
         del eng
         gc.collect()
-        weights = ref.make_weights(cell.cfg, seed)
-        gaps = harness.logit_gaps(cell, weights, sample)["program"]
-        del weights
+        gaps = harness.logit_gaps(cell, mesh, seed, sample)["program"]
         reqs = rec["requests"]
         print(json.dumps({
             key: value, "warm_s": warm_s, "sent": len(reqs),
@@ -114,7 +105,9 @@ def sweep_mode(cell, keys, values, seconds, seed, log):
             "tokens": sum(r["n"] for r in reqs), "ticks": len(rec["ticks"]),
             "chunk_ms_mean": 1e3 * sum(chunk) / max(1, len(chunk)),
             "metrics": {k: v["value"] for k, v in metrics.items()},
-            "stats": rec["stats"], "memory_peak_bytes_so_far": mem,
+            "stats": rec["stats"],
+            "memory_peak_bytes_so_far": harness.fullest(mem),
+            "memory_peaks_so_far": mem,
             "window_compiles": window_compiles, **gaps}), flush=True)
         del win
         gc.collect()

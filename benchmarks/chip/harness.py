@@ -13,10 +13,28 @@ also makes the weights) and the adapter that hands those weights to the
 program (``adapters/<type>.py``).  Adding a cell, a mix or a metric is
 adding files and entries; nothing here changes.
 
+A cell's ``chips`` sets its mesh, ``data=1 x model=chips`` on the first
+``chips`` devices (``cell_mesh``), and the mesh sets everything else: the
+weights are made in place on it, the ``Engine`` is built on it (and
+picks its sharded backend from it), the reference runs on it, the
+memory reading takes every chip of it and the fullest one is reported,
+and the trace reduction reads its chips' planes.
+
+A reference module provides ``make_weights(cfg, seed, mesh)``: one
+jitted call with ``out_shardings``, each weight split over the mesh's
+``model`` axis along an axis the reference names (replicated only where
+that axis does not divide), the same values on any mesh; and
+``best_and_picked(weights, cfg, tokens, positions, pick, control)``,
+which the harness calls under ``jax.set_mesh(mesh)``.  A reference for a
+model larger than one chip keeps its weights in the configuration's
+dtype and upcasts one layer at a time to float32 at ``highest``
+precision, since the whole model in float32 would not fit the mesh.
+
 From the program the run uses only its normal entry points: the
-adapter's ``ModelConfig`` and ``pack_params``, ``serving.Engine`` on the
-paged backend with ``submit`` / ``step`` / ``run`` / ``stats`` /
-``reset_stats``, and ``kernels.dispatch.fallback_count``.
+adapter's ``ModelConfig`` and ``pack_params``, ``launch.mesh.make_mesh``
+for the cell's mesh, ``serving.Engine`` on the paged backend with
+``submit`` / ``step`` / ``run`` / ``stats`` / ``reset_stats``, and
+``kernels.dispatch.fallback_count``.
 """
 
 from __future__ import annotations
@@ -138,18 +156,25 @@ def serve_config(cell, seed32: int):
         page_view_chunk=s["page_view_chunk"], seed=seed32)
 
 
-def build_engine(cell, seed: int, parts: Optional[dict] = None):
-    """Weights from ``seed`` (one jitted call on the device), packed by
-    the program, served by a paged ``Engine``.  ``parts`` collects the
-    seconds of each step."""
+def cell_mesh(cell):
+    """``data=1 x model=chips`` on the first ``chips`` devices."""
     import jax
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_mesh
+    return make_mesh((1, cell.chips), devices=jax.devices()[:cell.chips])
+
+
+def build_engine(cell, seed: int, parts: Optional[dict] = None):
+    """Weights from ``seed`` (one jitted call, in place on the cell's
+    mesh), packed by the program, served by a paged ``Engine`` on that
+    mesh.  ``parts`` collects the seconds of each step."""
+    import jax
     from repro.serving import Engine
     parts = {} if parts is None else parts
     t = time.monotonic()
+    mesh = cell_mesh(cell)
     ref = plugin(cell, "reference", cell.cfg["model_type"])
     adapter = plugin(cell, "adapters", cell.cfg["model_type"])
-    weights = jax.block_until_ready(ref.make_weights(cell.cfg, seed))
+    weights = jax.block_until_ready(ref.make_weights(cell.cfg, seed, mesh))
     parts["weights"] = time.monotonic() - t
     t = time.monotonic()
     mcfg = adapter.model_config(cell.cfg)
@@ -157,8 +182,7 @@ def build_engine(cell, seed: int, parts: Optional[dict] = None):
     del weights
     parts["pack"] = time.monotonic() - t
     t = time.monotonic()
-    engine = Engine(mcfg, make_host_mesh(), serve_config(cell,
-                                                         ref.seed32(seed)),
+    engine = Engine(mcfg, mesh, serve_config(cell, ref.seed32(seed)),
                     params)
     parts["engine"] = time.monotonic() - t
     return engine
@@ -377,27 +401,33 @@ def _gap_numbers(g: np.ndarray) -> dict:
             "compared_tokens": int(g.size)}
 
 
-def logit_gaps(cell, weights, sample, control: bool = False) -> dict:
+def logit_gaps(cell, mesh, seed: int, sample, control: bool = False
+               ) -> dict:
     """How far the served tokens' reference logits lie below the
     reference's best, over the sample: the widest gap and the mean gap
     per served token.  With ``control``, the same numbers for the control
     in the program's place: at each of those positions, the token that
-    the reference computed one precision step lower puts first."""
+    the reference computed one precision step lower puts first.  The
+    reference makes the weights from ``seed`` anew on ``mesh`` and runs
+    there; call it once the program's state is freed."""
+    import jax
     ref = plugin(cell, "reference", cell.cfg["model_type"])
+    weights = ref.make_weights(cell.cfg, seed, mesh)
     gaps, ctl_gaps = [], []
-    for rec in sample:
-        seq, pos = served_sequence(rec)
-        served = np.asarray(rec.tokens, np.int32)
-        pick = served[:, None]
-        if control:
-            _, ctl_first, _ = ref.best_and_picked(weights, cell.cfg, seq, pos,
-                                                  pick, control=True)
-            pick = np.stack([served, ctl_first], 1)
-        best, _, picked = ref.best_and_picked(weights, cell.cfg, seq, pos,
-                                              pick)
-        gaps.append(best - picked[:, 0])
-        if control:
-            ctl_gaps.append(best - picked[:, 1])
+    with jax.set_mesh(mesh):
+        for rec in sample:
+            seq, pos = served_sequence(rec)
+            served = np.asarray(rec.tokens, np.int32)
+            pick = served[:, None]
+            if control:
+                _, ctl_first, _ = ref.best_and_picked(
+                    weights, cell.cfg, seq, pos, pick, control=True)
+                pick = np.stack([served, ctl_first], 1)
+            best, _, picked = ref.best_and_picked(weights, cell.cfg, seq,
+                                                  pos, pick)
+            gaps.append(best - picked[:, 0])
+            if control:
+                ctl_gaps.append(best - picked[:, 1])
     out = {"program": _gap_numbers(np.concatenate(gaps) if gaps
                                    else np.zeros(0))}
     if control:
@@ -444,6 +474,24 @@ def read_metrics(cell, entries: List[dict], ctx) -> Dict[str, dict]:
     return out
 
 
+def memory_peaks(mesh) -> Dict[int, Optional[int]]:
+    """``peak_bytes_in_use`` of each chip of ``mesh`` by device id, in
+    mesh order (None where the backend reports none)."""
+    return {int(d.id): (d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in mesh.devices.flat}
+
+
+def fullest(peaks: Dict[int, Optional[int]]) -> Optional[int]:
+    """The fullest chip's peak."""
+    known = [p for p in peaks.values() if p is not None]
+    return max(known) if known else None
+
+
+def device_ids(mesh) -> List[int]:
+    """The ids of ``mesh``'s chips, in mesh order."""
+    return [int(d.id) for d in mesh.devices.flat]
+
+
 def context(cell, record, trace=None, peaks=None):
     import tracereduce
     import workcount
@@ -464,7 +512,6 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     device record, which the caller adds).  ``control`` (never in a
     benchmark run) compares the lower-precision control in place of the
     served tokens, which has to come out not correct."""
-    import jax
     from repro.kernels import dispatch
 
     log.phase = "setup"
@@ -493,17 +540,14 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     record["dispatch_fallbacks"] = dispatch.fallback_count() - fallbacks0
     record["warmup_requests"] = warmed
     record["window_compiles"] = log.count("window", since=mark)
-    dev = jax.devices()[0]
-    mem = (dev.memory_stats() or {}).get("peak_bytes_in_use")
-    # free the program's state before the reference takes the chip
+    mesh = engine.mesh
+    chip_peaks = memory_peaks(mesh)
+    # free the program's state before the reference takes the chips
     sample = check_sample(window, seed, cell.limits["sample_tokens"])
     del engine
     gc.collect()
     t_ref = time.monotonic()
-    ref = plugin(cell, "reference", cell.cfg["model_type"])
-    weights = ref.make_weights(cell.cfg, seed)
-    gaps = logit_gaps(cell, weights, sample, control=control)
-    del weights
+    gaps = logit_gaps(cell, mesh, seed, sample, control=control)
     record["reference_s"] = time.monotonic() - t_ref
     # with ``control`` the control stands in the program's place
     chk = checks(cell, record, gaps["control" if control else "program"],
@@ -514,8 +558,9 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         import tracereduce
         t = time.monotonic()
         path = tracereduce.find_xplane(trace_dir)
-        red = tracereduce.load(path) if path else None
+        red = tracereduce.load(path, device_ids(mesh)) if path else None
         record["trace_read_s"] = time.monotonic() - t
+        record["trace_chips"] = len(red.chips) if red else 0
     ctx = context(cell, record, red, peaks)
     metrics = read_metrics(cell, cell.per_layer if trace
                            else cell.end_to_end, ctx)
@@ -525,7 +570,8 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         "attempted": len(reqs),
         "failed": sum(r["failed"] for r in reqs),
         "metrics": metrics,
-        "memory_peak_bytes": mem,
+        "memory_peak_bytes": fullest(chip_peaks),
+        "memory_peaks": chip_peaks,
         "record": record,
         "gaps": gaps,
         "checks": chk,

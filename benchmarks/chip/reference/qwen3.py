@@ -3,9 +3,13 @@ forward pass, written from the published description (the Hugging Face
 ``Qwen3ForCausalLM`` modelling code and ``config.json``), importing
 nothing of the program under test.
 
-Weights.  ``make_weights(cfg, seed)`` draws every tensor on the device in
-one jitted call, in the layout ``x @ W`` (rows = input features), and
-prunes each projection the configuration's ``sparsity`` recipe lists:
+Weights.  ``make_weights(cfg, seed, mesh)`` draws every tensor in one
+jitted call, in the layout ``x @ W`` (rows = input features), made in
+place on ``mesh``: each tensor split over its ``model`` axis along the
+axis ``SPLIT`` names (replicated where that axis does not divide), so
+that no device ever holds more than its share.  The values do not depend
+on the mesh (JAX's partitionable threefry).  It prunes each projection
+the configuration's ``sparsity`` recipe lists:
 
   * ``nm``: keep ``n`` of every ``m`` consecutive input rows, the kept
     positions shared by a group of ``group`` output columns, ranked by
@@ -21,7 +25,10 @@ divide is left dense.
 
 Forward.  ``logits_at(weights, cfg, tokens, positions)`` runs one
 sequence through the model in float32 with every matmul at ``highest``
-precision and returns the logits at ``positions``.  It follows the
+precision and returns the logits at ``positions``.  The weights stay in
+their served dtype and each layer's are upcast to float32 inside the
+layer scan; called under ``jax.set_mesh``, the work is placed where the
+weights lie.  It follows the
 published equations: pre-norm blocks, RMSNorm ``x / sqrt(mean(x^2) +
 eps) * w``, per-head RMSNorm on q and k before rotary embeddings
 (rotate-half form, ``theta = rope_theta``), grouped-query attention
@@ -45,10 +52,17 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 ATTN = ("q", "k", "v", "o")
 MLP = ("gate", "up", "down")
 FP8_MAX = 448.0
+# the axis of each weight that a mesh's ``model`` axis splits: output
+# features of q, k, v, gate and up, input features of o and down, the
+# vocabulary of the embedding, the features of every norm
+SPLIT = {"q": -1, "k": -1, "v": -1, "gate": -1, "up": -1, "o": -2,
+         "down": -2, "embed": 0, "attn_norm": -1, "mlp_norm": -1,
+         "q_norm": -1, "k_norm": -1, "final_norm": -1}
 
 
 def seed32(seed: int) -> int:
@@ -118,7 +132,6 @@ def _norm_weight(key, shape):
     return 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnums=(0,))
 def _make(cfg_items, key):
     cfg = dict(cfg_items)
     cfg["sparsity"] = {k: dict(v) for k, v in cfg["sparsity"]}
@@ -157,9 +170,32 @@ def _frozen(cfg: dict):
     return tuple((k, cfg[k]) for k in keep if k in cfg) + (("sparsity", sp),)
 
 
-def make_weights(cfg: dict, seed: int) -> dict:
-    """The model's weights from ``seed``: one jitted call on the device."""
-    return _make(_frozen(cfg), jax.random.key(seed32(seed)))
+def shardings(abstract, mesh) -> dict:
+    """Each weight split over ``mesh``'s ``model`` axis along its
+    ``SPLIT`` axis, or replicated where the extent does not divide it."""
+    ext = mesh.shape["model"]
+
+    def one(path, a):
+        spec = [None] * a.ndim
+        axis = SPLIT[path[-1].key] % a.ndim
+        if a.shape[axis] % ext == 0:
+            spec[axis] = "model"
+        return NamedSharding(mesh, PartitionSpec(*spec))
+
+    return jax.tree_util.tree_map_with_path(one, abstract)
+
+
+@functools.lru_cache(maxsize=None)
+def _maker(frozen: tuple, mesh):
+    make = functools.partial(_make, frozen)
+    abstract = jax.eval_shape(make, jax.random.key(0))
+    return jax.jit(make, out_shardings=shardings(abstract, mesh))
+
+
+def make_weights(cfg: dict, seed: int, mesh) -> dict:
+    """The model's weights from ``seed``: one jitted call, made in place
+    on ``mesh``."""
+    return _maker(_frozen(cfg), mesh)(jax.random.key(seed32(seed)))
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,8 @@ of standard output.  ``--trace 1`` runs the window under the profiler
 and reports the per-layer metrics instead of the end-to-end ones.
 
 Earlier lines report the compiles counted inside the window, requests
-sent / succeeded / failed, generator lateness and peak device memory;
+sent / succeeded / failed, generator lateness and each chip's peak
+device memory;
 the last lines of standard error give each number compared beside its
 limit.  JAX's persistent compilation cache is kept at ``.jax_cache``
 in the checkout, and the trace at ``.bench_trace``.
@@ -91,10 +92,13 @@ def report(cell, res: dict, dev: dict) -> None:
         f"{k} {v:.2f} s" for k, v in rec["setup_parts"].items()))
     if "trace_read_s" in rec:
         print(f"[trace] traced {rec['traced']} s of the window, read in "
-              f"{rec['trace_read_s']:.2f} s")
+              f"{rec['trace_read_s']:.2f} s from {rec['trace_chips']} chip "
+              f"planes")
         for name, secs in res.get("top_ops", []):
             print(f"[trace] device op {name}: {secs:.6f} s")
-    print(f"[device] peak memory {res['memory_peak_bytes']} bytes")
+    print("[device] peak memory per chip: " + ", ".join(
+        f"chip {i} {b} bytes" for i, b in res["memory_peaks"].items())
+        + f"; fullest {res['memory_peak_bytes']} bytes")
     print(f"[check] reference took {rec['reference_s']:.2f} s over "
           f"{res['gaps']['program']['compared_tokens']} served tokens: "
           f"{res['gaps']}")

@@ -63,7 +63,8 @@ def main(argv=None) -> int:
     half = [p for (a, _), p in zip(rec["ticks"], st.chunk_pages)
             if a >= args.seconds / 2]
     t = time.monotonic()
-    red = tracereduce.load(tracereduce.find_xplane(trace_dir))
+    red = tracereduce.load(tracereduce.find_xplane(trace_dir),
+                           harness.device_ids(engine.mesh))
     rec["trace_read_s"] = time.monotonic() - t
     ctx = harness.context(cell, rec, red, peaks)
     ctx.trace_dir = trace_dir

@@ -1,15 +1,17 @@
 """Reduction of a JAX profiler trace (``.xplane.pb``) to the numbers the
 per-layer metrics read.
 
-A TPU trace holds one plane per chip (``/device:TPU:<i>``) whose
+A TPU trace holds one plane per chip (``/device:TPU:<id>``) whose
 ``XLA Ops`` line has one event per device operation and whose
 ``XLA Modules`` line has one event per run of a compiled program, plus
 the host plane, where the harness's own ``TraceAnnotation`` spans
-(``bench.*``) sit on the same clock.  From those:
+(``bench.*``) sit on the same clock.  Only the planes of the cell's
+chips are read, in mesh order; a reading of one chip reads the first.
+From those:
 
   * busy time: the union of the operation intervals inside the traced
     window (the harness's ``bench.traced`` span, else ``bench.window``),
-    averaged over chips;
+    averaged over the cell's chips;
   * idle gaps: the holes in that union, each named by the innermost
     harness span open at the gap's midpoint;
   * per-name operation totals, and the operations of one kernel inside
@@ -173,6 +175,7 @@ def find_xplane(log_dir: str) -> Optional[str]:
 
 
 OPERAND = re.compile(r"%[\w.\-]+")
+TPU_PLANE = re.compile(r"/device:TPU:(\d+)(?!\d)")
 CONTAINERS = ("while", "conditional", "call")
 
 
@@ -196,12 +199,20 @@ def program_name(module: str) -> str:
     return module.split("(", 1)[0]
 
 
-def load(path: str, host_prefix: str = "bench.") -> Trace:
+def load(path: str, device_ids: List[int], host_prefix: str = "bench."
+         ) -> Trace:
+    """The trace at ``path``: the planes of the chips ``device_ids``
+    names, in that order, and the host's ``host_prefix`` spans."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
+    order = {d: i for i, d in enumerate(device_ids)}
     chips, host = [], []
     for plane in pd.planes:
-        if plane.name.startswith("/device:TPU:") and "Host" not in plane.name:
+        tpu = TPU_PLANE.match(plane.name)
+        if tpu and "Host" not in plane.name:
+            at = order.get(int(tpu.group(1)))
+            if at is None:
+                continue
             ops, mods = [], []
             for line in plane.lines:
                 if line.name == "XLA Ops":
@@ -217,7 +228,7 @@ def load(path: str, host_prefix: str = "bench.") -> Trace:
                                        s + ev.duration_ns * 1e-9))
             chip = Chip(ops, mods)
             attribute_modules(chip)
-            chips.append(chip)
+            chips.append((at, chip))
         elif plane.name.startswith("/host:CPU"):
             for line in plane.lines:
                 for ev in line.events:
@@ -225,6 +236,7 @@ def load(path: str, host_prefix: str = "bench.") -> Trace:
                         s = ev.start_ns * 1e-9
                         host.append(Op(ev.name, s,
                                        s + ev.duration_ns * 1e-9))
+    chips = [c for _, c in sorted(chips, key=lambda ic: ic[0])]
     win = ([h for h in host if h.name == host_prefix + "traced"]
            or [h for h in host if h.name == host_prefix + "window"])
     if win:

@@ -10,6 +10,7 @@ import json
 import os
 import shutil
 import sys
+import types
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
 CHIP = os.path.join(REPO, "benchmarks", "chip")
@@ -17,8 +18,15 @@ if CHIP not in sys.path:
     sys.path.insert(0, CHIP)
 
 
-def reduced_config(base: str = "qwen3-0.6b-nm") -> dict:
-    """The committed configuration at ``qwen3_0_6b.reduced()``'s sizes."""
+def mesh(chips: int = 1):
+    """The mesh of a cell on ``chips`` chips."""
+    import harness
+    return harness.cell_mesh(types.SimpleNamespace(chips=chips))
+
+
+def reduced_config(base: str = "qwen3-0.6b-nm", kv_heads=None) -> dict:
+    """The committed configuration at ``qwen3_0_6b.reduced()``'s sizes
+    (with ``kv_heads`` key/value heads, where given)."""
     from repro.configs import qwen3_0_6b
     r = qwen3_0_6b.reduced()
     with open(os.path.join(CHIP, "configs", base + ".json")) as f:
@@ -26,7 +34,8 @@ def reduced_config(base: str = "qwen3-0.6b-nm") -> dict:
     cfg.update(name="qwen3-reduced", vocab_size=r.vocab_size,
                hidden_size=r.d_model, intermediate_size=r.d_ff,
                num_hidden_layers=r.n_layers, num_attention_heads=r.n_heads,
-               num_key_value_heads=r.n_kv_heads, head_dim=r.head_dim)
+               num_key_value_heads=kv_heads or r.n_kv_heads,
+               head_dim=r.head_dim)
     return cfg
 
 
@@ -45,14 +54,16 @@ def small_mix(name: str) -> dict:
 
 
 def make_checkout(tmp, mix: str = "chat", limit: float = 0.05,
-                  base: str = "qwen3-0.6b-nm", min_tokens: int = 40) -> str:
-    """A checkout holding the benchmark plus the cell ``small-cell``."""
+                  base: str = "qwen3-0.6b-nm", min_tokens: int = 40,
+                  chips: int = 1, kv_heads=None) -> str:
+    """A checkout holding the benchmark plus the cell ``small-cell`` on
+    ``chips`` chips."""
     root = str(tmp)
     shutil.copytree(CHIP, os.path.join(root, "benchmarks", "chip"),
                     ignore=shutil.ignore_patterns("__pycache__"))
     chip = os.path.join(root, "benchmarks", "chip")
     with open(os.path.join(chip, "configs", "qwen3-reduced.json"), "w") as f:
-        json.dump(reduced_config(base), f)
+        json.dump(reduced_config(base, kv_heads), f)
     with open(os.path.join(chip, "traffic", "small.json"), "w") as f:
         json.dump(small_mix(mix), f)
     with open(os.path.join(chip, "limits", "small-cell.json"), "w") as f:
@@ -66,7 +77,7 @@ def make_checkout(tmp, mix: str = "chat", limit: float = 0.05,
         "reduced": [], "why": "test"})
     bench["workloads"].append({"name": "small-cell",
                                "config": "qwen3-reduced",
-                               "traffic": "small", "chips": 1,
+                               "traffic": "small", "chips": chips,
                                "why": "test"})
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)

@@ -22,7 +22,7 @@ def test_reference_logits_match_the_program_forward():
     adapter = harness.load_module(
         os.path.join(H.CHIP, "adapters", "qwen3.py"), "adapter_qwen3_t")
     cfg = H.reduced_config()
-    w = ref.make_weights(cfg, 2**40 + 3)
+    w = ref.make_weights(cfg, 2**40 + 3, H.mesh())
     mcfg = adapter.model_config(cfg)
     params = adapter.program_params(w, mcfg)
     tokens = np.random.default_rng(0).integers(0, cfg["vocab_size"], 37)
@@ -39,7 +39,7 @@ def test_reduced_gaps_match_the_full_logits():
     ref = harness.load_module(
         os.path.join(H.CHIP, "reference", "qwen3.py"), "ref_qwen3_g")
     cfg = H.reduced_config()
-    w = ref.make_weights(cfg, 11)
+    w = ref.make_weights(cfg, 11, H.mesh())
     tokens = np.random.default_rng(1).integers(0, cfg["vocab_size"], 45)
     pos = np.arange(20, 45)
     pick = np.stack([tokens[21:46].tolist() + [0], np.arange(25)], 1)[:25]
@@ -66,7 +66,7 @@ def test_pruning_is_what_the_recipe_states():
         os.path.join(H.CHIP, "reference", "qwen3.py"), "ref_qwen3_p")
     cfg = H.reduced_config("qwen3-0.6b-csa")
     cfg.update(hidden_size=256, intermediate_size=512)
-    w = ref.make_weights(cfg, 5)
+    w = ref.make_weights(cfg, 5, H.mesh())
     up = np.asarray(w["layers"]["up"][0], np.float32)        # (256, 512)
     groups = (up.reshape(64, 4, 4, 128) != 0).any(-1)       # (K/4, 4, N/g)
     tiles = (up.reshape(2, 128, 4, 128) != 0).any((1, 3))   # (Kb, Nb)

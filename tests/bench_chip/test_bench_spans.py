@@ -53,7 +53,7 @@ def test_serve_spans_nest_under_the_profiler(tmp_path):
         ticks += 1
     jax.profiler.stop_trace()
     path = tr.find_xplane(str(tmp_path))
-    host = tr.load(path, host_prefix="serve.").host
+    host = tr.load(path, [0], host_prefix="serve.").host
     steps = [s for s in host if s.name == "serve.step"]
     assert len(steps) == ticks
     for s in host:
